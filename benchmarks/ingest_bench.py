@@ -141,8 +141,10 @@ def _e2e_pair(path, *, chunk_rows, tile, steps, lam1):
 
 
 def _dist_row(path, *, nprocs, chunk_rows, tile, steps, lam1):
-    """One ``dist_run --data`` job; returns its coordinator JSON row."""
+    """One ``dist_run --data`` job; returns its coordinator JSON row.  The
+    job is a multi-process CPU simulation: it runs on JAX_PLATFORMS=cpu."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(_REPO / "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     with tempfile.TemporaryDirectory() as td:
@@ -160,6 +162,8 @@ def _dist_row(path, *, nprocs, chunk_rows, tile, steps, lam1):
                 f"{proc.stderr}")
         row = json.loads(out.read_text())
     row["case"] = f"multihost_{nprocs}proc"
+    row["platform"] = "cpu (local worker processes)"
+    print(f"[ingest_bench] {row['case']}: workers ran on JAX_PLATFORMS=cpu")
     return row
 
 

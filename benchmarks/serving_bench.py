@@ -18,8 +18,9 @@ and int8 artifacts, then measure
 
 Full mode writes ``results/benchmarks/serving_bench.json`` (committed;
 ``benchmarks/make_report.py`` renders it).  Smoke mode shrinks everything
-and additionally round-trips the artifact through the real CLI
-(``python -m repro.launch.serve_glm --artifact ... --smoke``), asserting
+and additionally round-trips the artifact through the CLI's entry point
+(``serve_glm.main(["--artifact", ..., "--smoke"])``, in this process so
+only one process ever claims the accelerator), asserting
 the emitted JSON carries the p50 latency and rows/s fields — the CI
 serving smoke.
 """
@@ -28,7 +29,6 @@ from __future__ import annotations
 import argparse
 import json
 import pathlib
-import subprocess
 import sys
 import tempfile
 import time
@@ -102,6 +102,7 @@ def kernel_parity_rows():
 
 # one traffic generator: the CLI and this benchmark must measure the
 # SAME synthetic workload, not two drifting copies
+from repro.launch import serve_glm  # noqa: E402
 from repro.launch.serve_glm import synth_requests  # noqa: E402
 
 
@@ -218,18 +219,12 @@ def run(smoke: bool, out_path):
                  "n_active": eng32.n_active, "p": p})
 
     if smoke:
-        # CLI round trip: export -> serve_glm --smoke -> assert fields
+        # CLI round trip: export -> serve_glm --smoke -> assert fields,
+        # in this process (a child would contend for the accelerator)
         out_json = tmp / "serve_glm.json"
-        proc = subprocess.run(
-            [sys.executable, "-m", "repro.launch.serve_glm",
-             "--artifact", str(fp32_dir), "--smoke",
-             "--json", str(out_json)],
-            capture_output=True, text=True,
-            env={**__import__("os").environ,
-                 "PYTHONPATH": str(pathlib.Path(__file__).resolve()
-                                   .parents[1] / "src")})
-        assert proc.returncode == 0, \
-            f"serve_glm failed:\n{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}"
+        rc = serve_glm.main(["--artifact", str(fp32_dir), "--smoke",
+                             "--json", str(out_json)])
+        assert rc == 0, f"serve_glm --smoke exited {rc}"
         rec = json.loads(out_json.read_text())
         for field in ("p50_ms", "p99_ms", "rows_per_s"):
             assert isinstance(rec.get(field), float), \

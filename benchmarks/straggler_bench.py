@@ -145,6 +145,8 @@ def _run_arm(arm: str, *, rows: int, cols: int, tile: int, steps: int,
         if not res.ok:
             raise RuntimeError(f"straggler arm {arm} failed:\n"
                                f"{res.summary()}")
+        print(f"[straggler_bench] arm {arm}: 2 local workers ran on "
+              "JAX_PLATFORMS=cpu")
         return json.loads(out.read_text())
 
 

@@ -218,7 +218,6 @@ def audit_collective_sequence() -> AuditResult:
     """The sharded superstep's collective signature must be non-empty,
     deterministic across traces, and cond-free."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     n, p, T = 8, 16, 8
     cfg = DGLMNETConfig(lam1=0.1, lam2=0.01, tile_size=T, coupling="jacobi",
@@ -238,8 +237,8 @@ def audit_collective_sequence() -> AuditResult:
         state, metrics = step(*args)
         return state, metrics
 
-    sharded = shard_map(traced, mesh=mesh, in_specs=in_specs,
-                        out_specs=(st_spec, P()), check_rep=False)
+    sharded = jax.shard_map(traced, mesh=mesh, in_specs=in_specs,
+                            out_specs=(st_spec, P()), check_vma=False)
     args = _toy_args(n, p, T)
     sigs = [collective_signature(jax.make_jaxpr(sharded)(*args).jaxpr)
             for _ in range(2)]

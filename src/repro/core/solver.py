@@ -67,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import compile_cache
 from repro.core import dglmnet, glm
 from repro.core.dglmnet import DGLMNETConfig, FitResult, FitState
 from repro.data import design as design_lib
@@ -122,44 +123,6 @@ def _cached_superstep(key: tuple, build):
 def clear_superstep_cache():
     """Drop all cached compiled supersteps (tests / memory pressure)."""
     _SUPERSTEP_CACHE.clear()
-
-
-# ---------------------------------------------------------------------------
-# persistent compilation cache (cold-PROCESS startup; SNIPPETS.md Snippet 3)
-# ---------------------------------------------------------------------------
-
-_COMPILATION_CACHE_DIR: Optional[str] = None
-
-
-def _maybe_init_compilation_cache():
-    """Point jax's persistent compilation cache at the directory named by
-    ``REPRO_COMPILATION_CACHE`` (once per process; no-op when unset).
-
-    The in-process ``_SUPERSTEP_CACHE`` above removes re-jit cost across
-    fits of one session; this removes it across PROCESSES — a fresh
-    interpreter deserializes the XLA executable instead of re-compiling
-    (the 0.58–0.69 s ``compile_s`` in path_bench.json).  The min-compile-
-    time/entry-size thresholds are zeroed so every program is cached —
-    this repo's programs are few and heavily reused, the usual
-    small-program cache pollution tradeoff doesn't apply.
-    """
-    global _COMPILATION_CACHE_DIR
-    path = os.environ.get("REPRO_COMPILATION_CACHE")
-    if not path or _COMPILATION_CACHE_DIR == path:
-        return
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as cc)
-        cc.initialize_cache(path)
-    except Exception:
-        jax.config.update("jax_compilation_cache_dir", path)
-    for flag, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(flag, val)
-        except Exception:  # flag not present in this jax version
-            pass
-    _COMPILATION_CACHE_DIR = path
 
 
 # ---------------------------------------------------------------------------
@@ -314,7 +277,7 @@ class GLMSolver:
                  standardize: bool = False, fit_intercept: bool = False,
                  penalty_factor=None,
                  telemetry=None, fault_plan=None):
-        _maybe_init_compilation_cache()
+        compile_cache.init()
         config = DGLMNETConfig() if config is None else config
         if family is not None:
             fam = glm.resolve_family(family)
@@ -611,6 +574,35 @@ class GLMSolver:
     def info(self):
         return self._info
 
+    def device_bytes(self) -> dict:
+        """``{device id: bytes}`` of the placed design and row vectors —
+        where the session's data actually lives (a mesh spreads it; a
+        single-device session holds it all on one device)."""
+        out: "collections.Counter[int]" = collections.Counter()
+        for leaf in jax.tree.leaves((self._Xs, self._ys, self._wobs,
+                                     self._offsets)):
+            if isinstance(leaf, jax.Array):
+                for shard in leaf.addressable_shards:
+                    out[shard.device.id] += shard.data.nbytes
+        return dict(out)
+
+    def lower_superstep(self):
+        """``jax.stages.Lowered`` of this session's compiled superstep at
+        its placed arguments (zero iterate, full budgets, every coordinate
+        active) — ``.compile()`` then gives the program a fit runs, e.g. to
+        count its kernel launches or read its memory analysis."""
+        if self._streaming:
+            raise ValueError("streaming sessions run several programs per "
+                             "superstep; there is no single one to lower")
+        budgets = jnp.full((1,), self._n_tiles_local, jnp.int32) \
+            if self.mesh is None else dist_boot.put_global(
+                np.full((self._M,), self._n_tiles_local, np.int32),
+                self.mesh, self._feat_spec)
+        return self._superstep.lower(
+            self._Xs, self._ys, self._wobs, self._offsets, budgets,
+            jnp.zeros((2,), jnp.float32), self._active_ones, self._penf,
+            self._init_state(None))
+
     def _place_feat(self, arr):
         if self.mesh is None:
             return jnp.asarray(arr)
@@ -626,6 +618,16 @@ class GLMSolver:
             return jnp.asarray(arr)
         return dist_boot.put_global(np.asarray(arr), self.mesh,
                                     self._row_spec)
+
+    def _place_scalar(self, value, dtype):
+        """A replicated scalar of the fit state (μ, step).  On a mesh it is
+        placed replicated over the mesh, exactly as the compiled superstep
+        returns it, so the first superstep of every fit sees the same input
+        shardings as the later ones and the superstep compiles once."""
+        arr = np.asarray(value, dtype)
+        if self.mesh is None:
+            return jnp.asarray(arr)
+        return dist_boot.put_global(arr, self.mesh, P())
 
     def _host(self, arr) -> np.ndarray:
         """Host numpy copy of a device array — the collective all-gather
@@ -862,8 +864,9 @@ class GLMSolver:
         cursor = jnp.zeros((1,), jnp.int32) if self.mesh is None else \
             dist_boot.put_global(np.zeros((self._M,), np.int32),
                                  self.mesh, self._feat_spec)
-        return FitState(beta=beta, xb=xb, mu=jnp.float32(cfg.mu_init),
-                        cursor=cursor, step=jnp.int32(0))
+        return FitState(beta=beta, xb=xb,
+                        mu=self._place_scalar(cfg.mu_init, np.float32),
+                        cursor=cursor, step=self._place_scalar(0, np.int32))
 
     def _budgets(self):
         from repro.core import alb as alb_lib
@@ -1104,8 +1107,8 @@ class GLMSolver:
                     self._host(saved["beta"]))),
                 xb=self._place_row(self._adapt_rows(
                     self._host(saved["xb"]))),
-                mu=jnp.float32(np.asarray(saved["mu"])),
-                step=jnp.int32(md["next_it"] - 1))
+                mu=self._place_scalar(np.asarray(saved["mu"]), np.float32),
+                step=self._place_scalar(md["next_it"] - 1, np.int32))
             f_prev = md.get("f_prev", np.inf)
             start_it = int(md["next_it"])
         for it in range(start_it, max_outer + 1):
@@ -1549,7 +1552,7 @@ class GLMSolver:
                 xb=state.xb if self._streaming
                 else self._place_row(self._adapt_rows(
                     self._host(saved["xb"]))),
-                mu=jnp.float32(np.asarray(saved["mu"])))
+                mu=self._place_scalar(np.asarray(saved["mu"]), np.float32))
             saved_betas = self._adapt_cols(saved["path_betas"])
             betas_packed[:start_k] = saved_betas[:start_k]
             for name, arr in (("f", f), ("nnz", nnz),
@@ -1561,8 +1564,9 @@ class GLMSolver:
         for k in range(start_k, K):
             lam1 = float(lambdas[k])
             # fresh trust region per λ; warm β / margins carry over
-            state = state._replace(mu=jnp.float32(cfg.mu_init),
-                                   step=jnp.int32(0))
+            state = state._replace(
+                mu=self._place_scalar(cfg.mu_init, np.float32),
+                step=self._place_scalar(0, np.int32))
             if screen:
                 # sequential strong rule (Tibshirani et al. 2012):
                 # |g_j| = |[Xᵀ s(β_{k-1})]_j| ≥ pf_j (2λ_k − λ_{k-1}) — plus

@@ -40,6 +40,7 @@ superstep.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -122,17 +123,30 @@ class DesignMatrix:
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _tile_major(data, tile_size: int):
+    """(n, nt·T) → (nt, n_pad, T): the fused kernels' tile-major operand,
+    rows zero-padded to a multiple of ``ops.DENSE_ROW_BLOCK`` (one fused
+    transpose-and-pad, so building it holds no third copy of the design)."""
+    n = data.shape[0]
+    data_t = jnp.swapaxes(data.reshape(n, -1, tile_size), 0, 1)
+    pad = (-n) % ops.DENSE_ROW_BLOCK
+    return jnp.pad(data_t, ((0, 0), (0, pad), (0, 0))) if pad else data_t
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class DenseDesign(DesignMatrix):
     """Feature-padded dense design: ``data`` is (n_rows, n_tiles * T).
 
     ``data_t`` is an OPTIONAL cached tile-major transposed copy
-    ``(n_tiles, n_rows, T)`` built by ``dense_design`` — the layout the fused
+    ``(n_tiles, n_pad, T)`` built by ``dense_design`` — the layout the fused
     superstep kernels (DESIGN.md §8) grid over: tile t's rows are one
     contiguous (n, T) block, so the per-tile Gram is a single batched matmul
-    instead of an einsum re-gather.  It doubles the design's memory; sessions
-    that never take the fused path can pass ``None``.
+    instead of an einsum re-gather.  Its rows are zero-padded to
+    ``n_pad``, a multiple of ``ops.DENSE_ROW_BLOCK``, so the kernels never
+    re-pad it per call.  It doubles the design's memory; sessions that never
+    take the fused path can pass ``None``.
     """
 
     data: jnp.ndarray
@@ -147,14 +161,12 @@ class DenseDesign(DesignMatrix):
         return cls(leaves[0], aux[0], leaves[1])
 
     def tiles3(self):
-        """(n_tiles, n_rows, T) tile-major view — the cached ``data_t`` when
-        present, else transposed in-trace (correct but re-materialized per
-        call; the session builder caches it once)."""
+        """(n_tiles, n_pad, T) row-padded tile-major view — the cached
+        ``data_t`` when present, else built in-trace (correct but
+        re-materialized per call; a session caches it once)."""
         if self.data_t is not None:
             return self.data_t
-        n = self.data.shape[0]
-        return jnp.swapaxes(
-            self.data.reshape(n, self.n_tiles, self.tile_size), 0, 1)
+        return _tile_major(self.data, self.tile_size)
 
     @property
     def shape(self):
@@ -205,9 +217,7 @@ class DenseDesign(DesignMatrix):
         data = data * scale[None, :]
         out = DenseDesign(data, self.tile_size)
         if self.data_t is not None:     # rebuild the fused-layout cache
-            n = data.shape[0]
-            out.data_t = jnp.swapaxes(
-                data.reshape(n, self.n_tiles, self.tile_size), 0, 1)
+            out.data_t = _tile_major(data, self.tile_size)
         return out
 
     def to_dense(self):
@@ -932,10 +942,9 @@ def dense_design(X, tile_size: int):
     pad = (-p) % tile_size
     if pad:
         Xj = jnp.pad(Xj, ((0, 0), (0, pad)))
-    nt = Xj.shape[1] // tile_size
-    # tile-major transposed cache for the fused superstep (materialized
+    # row-padded tile-major cache for the fused superstep (materialized
     # eagerly, once per session — DenseDesign.tiles3)
-    data_t = jnp.swapaxes(Xj.reshape(n, nt, tile_size), 0, 1)
+    data_t = _tile_major(Xj, tile_size)
     return DenseDesign(Xj, tile_size, data_t), DesignInfo(shape=(n, p))
 
 

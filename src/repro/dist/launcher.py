@@ -9,8 +9,8 @@ exactly that:
     assert result.ok and "PARITY_OK" in result.outputs[0]
 
 Each worker gets the ``REPRO_DIST_*`` env vars (`bootstrap.initialize()`
-reads them), one CPU device
-(``XLA_FLAGS=--xla_force_host_platform_device_count=1`` unless the caller
+reads them), one CPU device (``JAX_PLATFORMS=cpu``,
+``XLA_FLAGS=--xla_force_host_platform_device_count=1`` unless the caller
 overrides), and a fresh coordinator port.  When any worker exits non-zero
 the rest are killed after ``grace_s`` — a dead process must fail the JOB,
 not leave N−1 peers wedged at a collective (their own ``guarded_barrier``
@@ -55,8 +55,13 @@ class JobResult:
 def worker_env(process_id: int, num_processes: int, coordinator: str, *,
                devices_per_process: int = 1) -> dict:
     """Env block one worker needs; exposed so callers embedding workers in
-    other harnesses (pytest-xdist, shell scripts) can reuse it."""
+    other harnesses (pytest-xdist, shell scripts) can reuse it.
+
+    Local workers are fake-device CPU simulations of a multi-host job, so
+    each one is pinned to ``JAX_PLATFORMS=cpu``: a worker started next to a
+    parent that holds the accelerator must never try to claim it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["REPRO_DIST_COORD"] = coordinator
     env["REPRO_DIST_NPROCS"] = str(num_processes)
     env["REPRO_DIST_PROCID"] = str(process_id)

@@ -7,7 +7,10 @@ grid and at every Armijo backtracking candidate; evaluating them together
 turns O(K) HBM sweeps of the margin vectors into one.
 
 Grid iterates over example blocks; the (1, K) output block is revisited by
-every grid step and accumulated in VMEM (initialized at step 0).
+every grid step and accumulated in VMEM (initialized at step 0).  The step
+sizes sit in SMEM (a scalar read per candidate), and candidate k's loss sum
+lands in lane k of the output row through a lane mask — Mosaic indexes
+neither in-register vectors nor VMEM lanes dynamically.
 """
 from __future__ import annotations
 
@@ -16,32 +19,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.glm_stats import _STATS
 
 
-def _kernel(y_ref, xb_ref, xdb_ref, mask_ref, alphas_ref, out_ref, *, family):
+def candidate_losses(alphas_ref, y, xb, xdb, mask, *, family):
+    """(1, K) row: lane k holds Σ mask·l(y, xb + alphas[k]·xdb) over this
+    block.  ``alphas_ref`` is the (K,) SMEM candidate array."""
+    K = alphas_ref.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+
+    def per_alpha(k, acc):
+        loss, _, _ = _STATS[family](y, xb + alphas_ref[k] * xdb)
+        tot = jnp.sum(loss * mask, axis=(0, 1), keepdims=True)     # (1, 1)
+        return acc + jnp.where(lane == k, tot, 0.0)
+
+    return jax.lax.fori_loop(0, K, per_alpha, jnp.zeros((1, K), jnp.float32))
+
+
+def _kernel(alphas_ref, y_ref, xb_ref, xdb_ref, mask_ref, out_ref, *,
+            family):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    y = y_ref[...]            # (B, C)
-    xb = xb_ref[...]
-    xdb = xdb_ref[...]
-    mask = mask_ref[...]
-    alphas = alphas_ref[...]  # (1, K)
-
-    K = alphas.shape[-1]
-
-    def per_alpha(k, acc):
-        a = jax.lax.dynamic_index_in_dim(alphas[0], k, keepdims=False)
-        loss, _, _ = _STATS[family](y, xb + a * xdb)
-        acc = jax.lax.dynamic_update_index_in_dim(
-            acc, jnp.sum(loss * mask), k, axis=0)
-        return acc
-
-    partial = jax.lax.fori_loop(0, K, per_alpha, jnp.zeros((K,), jnp.float32))
-    out_ref[...] += partial[None, :]
+    out_ref[...] += candidate_losses(alphas_ref, y_ref[...], xb_ref[...],
+                                     xdb_ref[...], mask_ref[...],
+                                     family=family)
 
 
 @functools.partial(jax.jit, static_argnames=("family", "block_rows", "interpret"))
@@ -56,11 +61,11 @@ def alpha_search_pallas(y2, xb2, xdb2, mask2, alphas, *, family,
     out = pl.pallas_call(
         functools.partial(_kernel, family=family),
         grid=grid,
-        in_specs=[dspec, dspec, dspec, dspec,
-                  pl.BlockSpec((1, K), lambda i: (0, 0))],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  dspec, dspec, dspec, dspec],
         out_specs=pl.BlockSpec((1, K), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, K), f32),
         interpret=interpret,
-    )(y2.astype(f32), xb2.astype(f32), xdb2.astype(f32), mask2.astype(f32),
-      alphas.astype(f32)[None, :])
+    )(alphas.astype(f32), y2.astype(f32), xb2.astype(f32), xdb2.astype(f32),
+      mask2.astype(f32))
     return out[0]

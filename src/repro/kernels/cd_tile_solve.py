@@ -8,7 +8,13 @@ minimizations where step j updates a T-vector by an axpy with row j of G.
 
 XLA is poor at this shape of computation (a scan of dynamic-slices over a
 matrix it keeps in HBM); Pallas pins G in VMEM for the whole chain and runs
-the T-step loop on-core. VMEM footprint: T² + 4T floats (T=512 ⇒ ~1.06 MB).
+the T-step loop on-core. VMEM footprint: T² + 6T floats (T=512 ⇒ ~1.06 MB).
+
+Mosaic does not index in-register vectors dynamically, so the chain
+(``solve_chain``) works on whole (1, T) rows and selects coordinate j with
+lane masks; row j of G comes straight from the VMEM ref (a dynamic sublane
+slice, which Mosaic does support).  The scalars (μ, ν, λ1, λ2) live in
+SMEM.
 
 The kernel is gridless (grid=(1,)) by design: tiles are coupled through the
 margin delta, so cross-tile parallelism would change the algorithm (Jacobi
@@ -22,52 +28,56 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# params vector layout (passed as a (1, 4) f32 array):
+# params vector layout (passed as a (4,) f32 SMEM array):
 MU, NU, LAM1, LAM2 = 0, 1, 2, 3
 
 
-def _kernel(G_ref, g_ref, h_ref, beta_ref, dbeta_ref, params_ref, pf_ref,
-            out_ref):
-    T = g_ref.shape[-1]
-    mu = params_ref[0, MU]
-    nu = params_ref[0, NU]
-    lam1 = params_ref[0, LAM1]
-    lam2 = params_ref[0, LAM2]
+def solve_chain(G_row, g, h, beta, pf, d0, mu, nu, lam1, lam2):
+    """Exact cyclic coordinate minimization over one tile (the
+    ``ref.cd_tile_solve`` chain) on (1, T) rows; ``G_row(j)`` returns row j
+    of the tile Gram as (1, T).  Returns the new Δβ as (1, T).
 
-    h = h_ref[0, :]
-    beta = beta_ref[0, :]
-    pf = pf_ref[0, :]
-    lam1v = lam1 * pf          # per-coordinate penalty factors (intercept: 0)
+    Coordinate j is visited once per pass, so its entering Δβ is ``d0[j]``
+    for the whole chain and only the gradient row changes between steps.
+    Each step therefore evaluates the update of EVERY coordinate from the
+    current gradient (a few T-wide vector ops) and keeps lane j: one masked
+    lane reduction extracts the step δ_j that the rank-1 gradient
+    correction needs.
+    """
+    T = g.shape[-1]
+    lam1v = lam1 * pf
     den = mu * h + nu + lam2 * pf
     den_safe = jnp.maximum(den, 1e-30)
+    c = mu * h * (beta + d0) + nu * beta
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
 
     def body(j, carry):
-        g, d = carry
-        # scalar loads — all operands live in VMEM/VREGs
-        g_j = jax.lax.dynamic_index_in_dim(g, j, keepdims=False)
-        d_j = jax.lax.dynamic_index_in_dim(d, j, keepdims=False)
-        b_j = jax.lax.dynamic_index_in_dim(beta, j, keepdims=False)
-        h_j = jax.lax.dynamic_index_in_dim(h, j, keepdims=False)
-        l1_j = jax.lax.dynamic_index_in_dim(lam1v, j, keepdims=False)
-        den_j = jax.lax.dynamic_index_in_dim(den, j, keepdims=False)
-        dens_j = jax.lax.dynamic_index_in_dim(den_safe, j, keepdims=False)
+        g_c, d = carry
+        num = g_c + c
+        u = jnp.sign(num) * jnp.maximum(jnp.abs(num) - lam1v, 0.0) / den_safe
+        # dead coordinate (all-zero column, nu == lam2 == 0): keep at 0
+        u = jnp.where(den > 0, u, beta)
+        d_new = u - beta
+        at_j = lane == j
+        delta = jnp.sum(jnp.where(at_j, d_new - d0, 0.0), axis=1,
+                        keepdims=True)                              # (1, 1)
+        # rank-1 correction of the tile gradient: g -= mu*delta*G[j, :]
+        # (G is symmetric, so row j is column j)
+        g_c = g_c - (mu * delta) * G_row(j)
+        return g_c, jnp.where(at_j, d_new, d)
 
-        num = g_j + mu * h_j * (b_j + d_j) + nu * b_j
-        u = jnp.sign(num) * jnp.maximum(jnp.abs(num) - l1_j, 0.0) / dens_j
-        u = jnp.where(den_j > 0, u, b_j)
-        d_new = u - b_j
-        delta = d_new - d_j
-        # rank-1 correction of the tile gradient: g -= mu*delta*G[:, j]
-        G_col = jax.lax.dynamic_slice(G_ref[...], (0, j), (T, 1))[:, 0]
-        g = g - mu * delta * G_col
-        d = jax.lax.dynamic_update_index_in_dim(d, d_new, j, axis=0)
-        return g, d
+    _, d = jax.lax.fori_loop(0, T, body, (g, d0))
+    return d
 
-    g0 = g_ref[0, :]
-    d0 = dbeta_ref[0, :]
-    _, d_final = jax.lax.fori_loop(0, T, body, (g0, d0))
-    out_ref[0, :] = d_final
+
+def _kernel(params_ref, G_ref, g_ref, h_ref, beta_ref, dbeta_ref, pf_ref,
+            out_ref):
+    out_ref[...] = solve_chain(
+        lambda j: G_ref[pl.ds(j, 1), :], g_ref[...], h_ref[...],
+        beta_ref[...], pf_ref[...], dbeta_ref[...],
+        params_ref[MU], params_ref[NU], params_ref[LAM1], params_ref[LAM2])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -78,28 +88,25 @@ def cd_tile_solve_pallas(G, g, h, beta_t, dbeta_t, params, penf, *,
     Returns new dbeta_t (T,)."""
     T = g.shape[0]
     f32 = jnp.float32
+    row = pl.BlockSpec((1, T), lambda i: (0, 0))
     out = pl.pallas_call(
         _kernel,
         grid=(1,),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),    # params
             pl.BlockSpec((T, T), lambda i: (0, 0)),   # G      — VMEM resident
-            pl.BlockSpec((1, T), lambda i: (0, 0)),   # g
-            pl.BlockSpec((1, T), lambda i: (0, 0)),   # h
-            pl.BlockSpec((1, T), lambda i: (0, 0)),   # beta
-            pl.BlockSpec((1, T), lambda i: (0, 0)),   # dbeta
-            pl.BlockSpec((1, 4), lambda i: (0, 0)),   # params
-            pl.BlockSpec((1, T), lambda i: (0, 0)),   # penalty factors
+            row, row, row, row, row,                  # g, h, beta, dbeta, pf
         ],
-        out_specs=pl.BlockSpec((1, T), lambda i: (0, 0)),
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((1, T), f32),
         interpret=interpret,
     )(
+        params.astype(f32),
         G.astype(f32),
         g.astype(f32)[None, :],
         h.astype(f32)[None, :],
         beta_t.astype(f32)[None, :],
         dbeta_t.astype(f32)[None, :],
-        params.astype(f32)[None, :],
         penf.astype(f32)[None, :],
     )
     return out[0]

@@ -7,12 +7,16 @@ Each op dispatches between:
     by the partitioner and XLA fusion is adequate.
 
 The default is chosen per jax backend; tests exercise both and assert they
-agree.
+agree.  On a TPU every dispatch that lands on the oracle anyway (a caller's
+``backend="ref"``, the ``REPRO_KERNEL_BACKEND`` override, a family without a
+Pallas body, a layout the fused kernels do not take) is counted while an
+``oracle_trace()`` is active, so a run that meant to exercise the kernels
+can list what did not.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
-import functools
 import os
 
 import jax
@@ -31,6 +35,13 @@ from repro.kernels.superstep_tile import stats_gram_solve_pallas
 from repro.kernels.tile_gram import tile_gram_pallas
 
 _LANES = 128
+
+# Rows of the dense tile-major operand are padded to a multiple of this once,
+# when the design is built (data/design.py), so the fused kernels never copy
+# it per call: 1024 examples are one (8, 128) tile of the packed vectors.
+DENSE_ROW_BLOCK = 1024
+# VMEM budget of one block of the scoring kernel's weight table
+_TABLE_BLOCK_BYTES = 4 << 20
 
 # --- trace-time launch accounting (repro.analysis.audit) -------------------
 # Every public dispatch entry below records a logical launch event while a
@@ -59,11 +70,52 @@ def record_launch(name):
         _LAUNCH_EVENTS.append(name)
 
 
+# --- oracle dispatches on a TPU -------------------------------------------
+# Like the launch events: recorded at trace time while ``oracle_trace()`` is
+# active, so a run meant to exercise the kernels can list what did not.
+_ORACLE_EVENTS = None
+
+
+@contextlib.contextmanager
+def oracle_trace():
+    """Collect, on a TPU, the dispatches that resolve to the jnp oracle
+    while tracing; yields a live ``Counter`` of ``(op, reason)``."""
+    global _ORACLE_EVENTS
+    prev = _ORACLE_EVENTS
+    _ORACLE_EVENTS = events = collections.Counter()
+    try:
+        yield events
+    finally:
+        _ORACLE_EVENTS = prev
+
+
 def default_backend() -> str:
     env = os.environ.get("REPRO_KERNEL_BACKEND")
     if env:
         return env
     return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def _resolve(op, backend, *, fallback=None):
+    """The backend one dispatch of ``op`` runs on.  ``fallback`` names why
+    this call cannot take the Pallas kernel (None when it can); on a TPU a
+    dispatch that lands on the oracle is recorded with its reason."""
+    if backend is not None:
+        why = "backend='ref' requested"
+    else:
+        backend = default_backend()
+        why = "REPRO_KERNEL_BACKEND=ref"
+    if backend != "ref" and fallback is not None:
+        backend, why = "ref", fallback
+    if backend == "ref" and _ORACLE_EVENTS is not None and \
+            jax.default_backend() == "tpu":
+        _ORACLE_EVENTS[(op, why)] += 1
+    return backend
+
+
+def _no_pallas_body(fname):
+    return None if fname in _PALLAS_STATS else \
+        f"family {fname!r} has no Pallas stats body"
 
 
 def _interpret() -> bool:
@@ -95,17 +147,13 @@ def cd_tile_solve(G, g, h, beta_t, dbeta_t, mu, nu, lam1, lam2, *,
     solved under (lam1·penf_j, lam2·penf_j); 0 = unpenalized (intercept).
     """
     record_launch("cd_tile_solve")
-    backend = backend or default_backend()
-    if backend == "ref":
+    if _resolve("cd_tile_solve", backend) == "ref":
         return ref.cd_tile_solve(G, g, h, beta_t, dbeta_t, mu, nu, lam1,
                                  lam2, penf=penf)
-    params = jnp.stack([jnp.asarray(mu, jnp.float32),
-                        jnp.asarray(nu, jnp.float32),
-                        jnp.asarray(lam1, jnp.float32),
-                        jnp.asarray(lam2, jnp.float32)])
     if penf is None:
         penf = jnp.ones_like(g)
-    return cd_tile_solve_pallas(G, g, h, beta_t, dbeta_t, params, penf,
+    return cd_tile_solve_pallas(G, g, h, beta_t, dbeta_t,
+                                _params(mu, nu, lam1, lam2), penf,
                                 interpret=_interpret())
 
 
@@ -117,8 +165,7 @@ def tile_gram(bricks, rows, n_valid, w2, r2, *, backend=None):
     skipped (predicated off in the Pallas kernel).
     """
     record_launch("tile_gram")
-    backend = backend or default_backend()
-    if backend == "ref":
+    if _resolve("tile_gram", backend) == "ref":
         return ref.tile_gram(bricks, rows, n_valid, w2, r2)
     return tile_gram_pallas(bricks, rows, n_valid, w2, r2,
                             interpret=_interpret())
@@ -137,10 +184,8 @@ def glm_stats(y, xb, family, *, weights=None, offset=None, backend=None,
     ``offset`` shifts the margins (stats evaluated at ``xb + offset``).
     """
     record_launch("glm_stats")
-    backend = backend or default_backend()
     fname = _family_name(family)
-    if fname not in _PALLAS_STATS and backend != "ref":
-        backend = "ref"      # families without a Pallas stats body
+    backend = _resolve("glm_stats", backend, fallback=_no_pallas_body(fname))
     n = y.shape[0]
     if weights is None:
         weights = jnp.ones((n,), jnp.float32)
@@ -170,34 +215,38 @@ def predict_tile(slots, vals, table, b0, family, *, kind="link",
     does any non-TPU backend by default (kernels/predict_tile.py).
     """
     record_launch("predict_tile")
-    backend = backend or default_backend()
     fname = _family_name(family)
-    if fname not in _PALLAS_LINKS and backend != "ref":
-        backend = "ref"      # families without a Pallas link body
+    backend = _resolve(
+        "predict_tile", backend,
+        fallback=None if fname in _PALLAS_LINKS
+        else f"family {fname!r} has no Pallas link body")
     b0 = jnp.asarray(b0, jnp.float32).reshape(1, -1)
     if backend == "ref":
         return ref.predict_tile(slots, vals, table, b0, fname, kind=kind)
-    # TPU tiling: pad every LAST dim to the 128-lane width and the table's
-    # sublane dim to a multiple of 8, like _pack_2d does for the training
-    # kernels — Mosaic rejects unaligned tiles that interpret mode forgives.
-    # Padding is inert by construction: extra request slots point at the
-    # trailing all-zero row with value 0, extra table rows/columns are 0.
+    # TPU tiling: the request block is ``block_b`` rows, the table's lanes
+    # are padded to 128 and its rows to whole ``table_rows`` blocks (a
+    # multiple of 8 sublanes) of at most _TABLE_BLOCK_BYTES.  Padding is inert by construction: extra
+    # request rows point at the trailing all-zero row with value 0, extra
+    # table rows/columns are 0.  Slots live in SMEM, so J needs no padding.
     B, J = slots.shape
     A1, L = table.shape
     zero_row = A1 - 1
-    pad_b, pad_j = (-B) % block_b, (-J) % _LANES
-    pad_a, pad_l = (-A1) % 8, (-L) % _LANES
-    if pad_b or pad_j:
-        slots = jnp.pad(slots, ((0, pad_b), (0, pad_j)),
+    L_pad = L + (-L) % _LANES
+    table_rows = min(A1 + (-A1) % 8,
+                     max(8, _TABLE_BLOCK_BYTES // (4 * L_pad) // 8 * 8))
+    pad_b = (-B) % block_b
+    pad_a, pad_l = (-A1) % table_rows, L_pad - L
+    if pad_b:
+        slots = jnp.pad(slots, ((0, pad_b), (0, 0)),
                         constant_values=zero_row)
-        vals = jnp.pad(vals, ((0, pad_b), (0, pad_j)))
+        vals = jnp.pad(vals, ((0, pad_b), (0, 0)))
     if pad_a or pad_l:
         table = jnp.pad(table, ((0, pad_a), (0, pad_l)))
     if pad_l:
         b0 = jnp.pad(b0, ((0, 0), (0, pad_l)))
     out = predict_tile_pallas(slots, vals, table, b0, family=fname,
                               kind=kind, block_b=block_b,
-                              interpret=_interpret())
+                              table_rows=table_rows, interpret=_interpret())
     return out[:B, :L]
 
 
@@ -209,9 +258,28 @@ def predict_tile(slots, vals, table, b0, family, *, kind="link",
 # ---------------------------------------------------------------------------
 
 
+def _pad_rows(n_pad, *vecs):
+    """Zero-extend (n,) row vectors to the design's padded row count (the
+    padded rows carry observation weight 0, so they are inert)."""
+    return [v if v is None or v.shape[0] == n_pad
+            else jnp.pad(v, (0, n_pad - v.shape[0])) for v in vecs]
+
+
+def _fused_fallback(design, fname):
+    if not hasattr(design, "tiles3"):
+        return ("fused superstep on a non-dense design composes the jnp "
+                "oracle")
+    return _no_pallas_body(fname)
+
+
+def _params(mu, nu, lam1, lam2):
+    return jnp.stack([jnp.asarray(v, jnp.float32)
+                      for v in (mu, nu, lam1, lam2)])
+
+
 def fused_stats_sweep(design, y, xb, beta, family, *, mu, nu, lam1, lam2,
                       weights=None, offset=None, penf=None, tile_live=None,
-                      precision="fp32", backend=None, block_n=512):
+                      precision="fp32", backend=None):
     """Fused launch 1 of the superstep: link stats + every tile's Gram and
     gradient + the per-tile Jacobi CD solve, in one pass over the rows.
 
@@ -222,15 +290,15 @@ def fused_stats_sweep(design, y, xb, beta, family, *, mu, nu, lam1, lam2,
     fallback) — callers must not read them.
 
     Backend choice: the Pallas two-launch pipeline needs the dense
-    tile-major layout; BlockSparseDesign and non-TPU backends use the jnp
-    oracle composition in ref.py (same batched-matmul shaping, same
+    tile-major layout, whose rows ``dense_design`` already padded to a
+    multiple of ``DENSE_ROW_BLOCK``; BlockSparseDesign and non-TPU backends use the
+    jnp oracle composition in ref.py (same batched-matmul shaping, same
     active-set compaction, XLA-fused on CPU).
     """
     record_launch("fused_stats_sweep")
-    backend = backend or default_backend()
     fname = _family_name(family)
-    if fname not in _PALLAS_STATS and backend != "ref":
-        backend = "ref"
+    backend = _resolve("fused_stats_sweep", backend,
+                       fallback=_fused_fallback(design, fname))
     n = y.shape[0]
     T = design.tile_size
     nt = beta.shape[0] // T
@@ -240,11 +308,15 @@ def fused_stats_sweep(design, y, xb, beta, family, *, mu, nu, lam1, lam2,
               else penf.reshape(nt, T))
     beta_r = beta.reshape(nt, T)
 
-    if backend == "ref" or not hasattr(design, "tiles3"):
+    if backend == "ref":
         if hasattr(design, "tiles3"):
+            Xt3 = design.tiles3()
+            y_p, xb_p, w_p, off_p = _pad_rows(Xt3.shape[1], y, xb, weights,
+                                              offset)
             loss_i, s, w, G_all, g_all = ref.fused_stats_gram_dense(
-                design.tiles3(), y, xb, weights, fname, offset=offset,
+                Xt3, y_p, xb_p, w_p, fname, offset=off_p,
                 tile_live=tile_live, precision=precision)
+            loss_i, s, w = loss_i[:n], s[:n], w[:n]
         elif hasattr(design, "gather_all_tiles"):
             b3, rows, valid = design.gather_all_tiles()
             loss_i, s, w, G_all, g_all = ref.fused_stats_gram_bricks(
@@ -262,12 +334,7 @@ def fused_stats_sweep(design, y, xb, beta, family, *, mu, nu, lam1, lam2,
         Xt3 = design.tiles3()
         if offset is not None:
             xb = xb + offset
-        br = block_n // _LANES
-        packed, pad_mask, total = _pack_2d(y, xb, weights, block_rows=br)
-        y2, xb2, w_user = packed
-        mask2 = w_user * pad_mask
-        if total > Xt3.shape[1]:
-            Xt3 = jnp.pad(Xt3, ((0, 0), (0, total - Xt3.shape[1]), (0, 0)))
+        (y2, xb2, w_user), pad_mask = _pack_rows(Xt3, y, xb, weights)
         if tile_live is None:
             sel = jnp.concatenate([jnp.arange(nt, dtype=jnp.int32),
                                    jnp.full((1,), nt, jnp.int32)])
@@ -275,13 +342,11 @@ def fused_stats_sweep(design, y, xb, beta, family, *, mu, nu, lam1, lam2,
             live_i = tile_live.astype(jnp.int32)
             order = jnp.argsort(1 - live_i, stable=True).astype(jnp.int32)
             sel = jnp.concatenate([order, jnp.sum(live_i)[None]])
-        params = jnp.stack([jnp.asarray(mu, jnp.float32),
-                            jnp.asarray(nu, jnp.float32),
-                            jnp.asarray(lam1, jnp.float32),
-                            jnp.asarray(lam2, jnp.float32)])
         loss2, s2, w2, G_all, g_all, dbeta_r = stats_gram_solve_pallas(
-            sel, Xt3, y2, xb2, mask2, beta_r, penf_r, params, family=fname,
-            block_n=block_n, precision=precision, interpret=_interpret())
+            sel, Xt3, y2, xb2, w_user * pad_mask, beta_r, penf_r,
+            _params(mu, nu, lam1, lam2), family=fname,
+            block_n=DENSE_ROW_BLOCK, precision=precision,
+            interpret=_interpret())
         flat = lambda a: a.reshape(-1)[:n]
         loss_i, s, w = flat(loss2), flat(s2), flat(w2)
     if tile_live is not None:
@@ -289,26 +354,44 @@ def fused_stats_sweep(design, y, xb, beta, family, *, mu, nu, lam1, lam2,
     return loss_i, s, w, dbeta_r.reshape(-1), G_all, g_all
 
 
+def _pack_rows(Xt3, *vecs):
+    """Pack row vectors for the fused kernels: (R, 128) blocks plus the
+    padding mask, with R·128 equal to the tile-major operand's row count
+    (padded once, by ``data.design.dense_design``)."""
+    packed, pad_mask, total = _pack_2d(
+        *vecs, block_rows=DENSE_ROW_BLOCK // _LANES)
+    if total != Xt3.shape[1]:
+        raise ValueError(
+            f"tile-major operand has {Xt3.shape[1]} rows; the fused kernels "
+            f"need {total} ({vecs[0].shape[0]} rows padded to "
+            f"{DENSE_ROW_BLOCK}) — build the design with "
+            "data.design.dense_design")
+    return packed, pad_mask
+
+
 def fused_ls(design, y, xb, dbeta, alphas, family, *, weights=None,
-             offset=None, precision="fp32", backend=None, block_n=512):
+             offset=None, precision="fp32", backend=None):
     """Fused launch 2 of the superstep: margin delta xdb = X·Δβ plus every
     line-search candidate's loss in one pass.  Returns (xdb (n,),
     losses (K,)).  Non-dense designs and non-TPU backends compose the
     design's matvec with the alpha_search oracle instead (the margin vector
     round-trips once, which XLA fusion absorbs on CPU)."""
     record_launch("fused_ls")
-    backend = backend or default_backend()
     fname = _family_name(family)
-    if fname not in _PALLAS_STATS and backend != "ref":
-        backend = "ref"
+    backend = _resolve("fused_ls", backend,
+                       fallback=_fused_fallback(design, fname))
     n = y.shape[0]
     if weights is None:
         weights = jnp.ones((n,), jnp.float32)
-    if backend == "ref" or not hasattr(design, "tiles3"):
+    if backend == "ref":
         if hasattr(design, "tiles3"):
+            Xt3 = design.tiles3()
+            y_p, xb_p, w_p, off_p = _pad_rows(Xt3.shape[1], y, xb, weights,
+                                              offset)
             xdb, losses = ref.fused_ls_dense(
-                design.tiles3(), y, xb, dbeta, weights, alphas, fname,
-                offset=offset, precision=precision)
+                Xt3, y_p, xb_p, dbeta, w_p, alphas, fname, offset=off_p,
+                precision=precision)
+            xdb = xdb[:n]
         else:
             xdb = design.matvec(dbeta)
             losses = ref.alpha_search(y, xb, xdb, weights, alphas, fname,
@@ -319,31 +402,21 @@ def fused_ls(design, y, xb, dbeta, alphas, family, *, weights=None,
     nt = dbeta.shape[0] // T
     if offset is not None:
         xb = xb + offset
-    br = block_n // _LANES
-    packed, pad_mask, total = _pack_2d(y, xb, weights, block_rows=br)
-    y2, xb2, w_user = packed
-    mask2 = w_user * pad_mask
-    if total > Xt3.shape[1]:
-        Xt3 = jnp.pad(Xt3, ((0, 0), (0, total - Xt3.shape[1]), (0, 0)))
-    K = alphas.shape[0]
-    pad_k = (-K) % _LANES
-    if pad_k:   # pad the candidate grid with duplicates of alphas[0]
-        alphas = jnp.concatenate(
-            [alphas, jnp.broadcast_to(alphas[0], (pad_k,))])
+    (y2, xb2, w_user), pad_mask = _pack_rows(Xt3, y, xb, weights)
     xdb2, losses = margin_ls_pallas(
-        Xt3, dbeta.reshape(nt, T), y2, xb2, mask2, alphas, family=fname,
-        block_n=block_n, precision=precision, interpret=_interpret())
-    return xdb2.reshape(-1)[:n], losses[:K]
+        Xt3, dbeta.reshape(nt, T), y2, xb2, w_user * pad_mask, alphas,
+        family=fname, block_n=DENSE_ROW_BLOCK, precision=precision,
+        interpret=_interpret())
+    return xdb2.reshape(-1)[:n], losses
 
 
 def alpha_search(y, xb, xdb, alphas, family, *, weights=None, offset=None,
                  backend=None, block_rows=256):
     """losses[k] = sum_i weights_i * l(y_i, xb_i + o_i + alphas[k]*xdb_i)."""
     record_launch("alpha_search")
-    backend = backend or default_backend()
     fname = _family_name(family)
-    if fname not in _PALLAS_STATS and backend != "ref":
-        backend = "ref"      # families without a Pallas stats body
+    backend = _resolve("alpha_search", backend,
+                       fallback=_no_pallas_body(fname))
     n = y.shape[0]
     if weights is None:
         weights = jnp.ones((n,), jnp.float32)

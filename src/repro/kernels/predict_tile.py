@@ -15,12 +15,14 @@ what keeps a micro-batched request batch at a single device round-trip:
 three HBM sweeps (gather rows, accumulate, elementwise link) collapse into
 one pass where each gathered table row is consumed from VMEM immediately.
 
-Layout: the whole compacted table lives in VMEM — the active set of an
-L1-regularized model is small by construction (that is the point of the
-penalty), so A·L floats fit comfortably; requests stream through the grid
-in ``block_b``-row blocks.  The accumulation loop runs over the padded
-``nnz`` dimension with a per-j row gather (``jnp.take`` along the table's
-row axis).
+Layout: requests stream through the grid in ``block_b``-row blocks whose
+(slot, value) pairs sit in SMEM, so each gathered table row is a scalar
+slot read followed by a dynamic sublane load from VMEM — Mosaic has no
+vector gather.  The compacted table is tiled along its rows
+(``table_rows`` per block, a second "arbitrary" grid axis): the active set
+of an L1-regularized model is small by construction, so one block usually
+holds it all, and a larger one streams through in blocks while each
+request row accumulates the slots that fall inside the current block.
 
 ``ops.predict_tile`` wraps this with padding and dispatches to the
 pure-jnp oracle (``ref.predict_tile``) on backends without Pallas support —
@@ -34,6 +36,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _SQRT2 = 1.4142135623730951
 
@@ -49,39 +52,54 @@ _LINKS = {
 
 def _kernel(slots_ref, vals_ref, table_ref, b0_ref, out_ref, *,
             family, kind, nnz):
-    slots = slots_ref[...]              # (Bb, J) i32 — compacted table rows
-    vals = vals_ref[...]                # (Bb, J) f32
-    table = table_ref[...]              # (A1, L) f32; row A1-1 is all-zero
+    t = pl.program_id(1)
+    a_blk, L = table_ref.shape
+    lo = t * a_blk
 
-    def body(j, acc):
-        rows = jnp.take(table, slots[:, j], axis=0)       # (Bb, L)
-        return acc + vals[:, j][:, None] * rows
+    @pl.when(t == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    acc = jax.lax.fori_loop(
-        0, nnz, body, jnp.zeros(out_ref.shape, jnp.float32))
-    m = acc + b0_ref[...]               # (1, L) intercept broadcast
-    out_ref[...] = _LINKS[family](m) if kind == "response" else m
+    def per_row(b, carry):
+        def per_slot(j, acc):
+            local = slots_ref[b, j] - lo
+            inside = (local >= 0) & (local < a_blk)
+            row = table_ref[pl.ds(jnp.where(inside, local, 0), 1), :]
+            return acc + jnp.where(inside, vals_ref[b, j], 0.0) * row
+
+        acc = jax.lax.fori_loop(0, nnz, per_slot,
+                                jnp.zeros((1, L), jnp.float32))
+        out_ref[pl.ds(b, 1), :] += acc
+        return carry
+
+    jax.lax.fori_loop(0, out_ref.shape[0], per_row, 0)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _link():
+        m = out_ref[...] + b0_ref[...]      # (1, L) intercept broadcast
+        out_ref[...] = _LINKS[family](m) if kind == "response" else m
 
 
 @functools.partial(jax.jit, static_argnames=("family", "kind", "block_b",
-                                             "interpret"))
+                                             "table_rows", "interpret"))
 def predict_tile_pallas(slots, vals, table, b0, *, family, kind="link",
-                        block_b=8, interpret=True):
+                        block_b=8, table_rows=None, interpret=True):
     """slots/vals: (B, J) with B % block_b == 0; table: (A1, L) f32 whose
-    LAST row is all-zero (the padding target); b0: (1, L).  Returns (B, L)
-    margins (``kind="link"``) or family responses (``kind="response"``)."""
+    LAST row is all-zero (the padding target), A1 % table_rows == 0;
+    b0: (1, L).  Returns (B, L) margins (``kind="link"``) or family
+    responses (``kind="response"``)."""
     B, J = slots.shape
     A1, L = table.shape
-    grid = (B // block_b,)
-    req_spec = pl.BlockSpec((block_b, J), lambda i: (i, 0))
-    tab_spec = pl.BlockSpec((A1, L), lambda i: (0, 0))
-    b0_spec = pl.BlockSpec((1, L), lambda i: (0, 0))
-    out_spec = pl.BlockSpec((block_b, L), lambda i: (i, 0))
+    table_rows = A1 if table_rows is None else table_rows
+    req_spec = pl.BlockSpec((block_b, J), lambda i, t: (i, 0),
+                            memory_space=pltpu.SMEM)
     return pl.pallas_call(
         functools.partial(_kernel, family=family, kind=kind, nnz=J),
-        grid=grid,
-        in_specs=[req_spec, req_spec, tab_spec, b0_spec],
-        out_specs=out_spec,
+        grid=(B // block_b, A1 // table_rows),
+        in_specs=[req_spec, req_spec,
+                  pl.BlockSpec((table_rows, L), lambda i, t: (t, 0)),
+                  pl.BlockSpec((1, L), lambda i, t: (0, 0))],
+        out_specs=pl.BlockSpec((block_b, L), lambda i, t: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, L), jnp.float32),
         interpret=interpret,
     )(slots.astype(jnp.int32), vals.astype(jnp.float32),

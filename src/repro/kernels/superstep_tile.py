@@ -35,7 +35,10 @@ matmul INPUTS to bf16 with f32 accumulation (``preferred_element_type``);
 the link stats, the solve chain, and the Armijo loss sums stay f32.
 
 Shapes follow ops._pack_2d: vectors as (R, 128) with a mask folding weights
-and padding; rows are padded to a multiple of ``block_n`` examples.  As with
+and padding; rows are padded to a multiple of ``block_n`` examples (1024 by
+default, so a row block's vectors are one (8, 128) tile).  Inside a block,
+example ``r·128 + l`` sits at (r, l) of the vector tile and at row
+``r·128 + l`` of the design block; ``_lane_row`` lines the two up.  As with
 the other kernels in this package, CPU/GPU runs use interpret mode.
 """
 from __future__ import annotations
@@ -47,42 +50,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.alpha_search import candidate_losses
+from repro.kernels.cd_tile_solve import solve_chain
 from repro.kernels.glm_stats import _STATS
 
-MU, NU, LAM1, LAM2 = 0, 1, 2, 3  # params (1, 4) layout, as cd_tile_solve
+MU, NU, LAM1, LAM2 = 0, 1, 2, 3  # params (4,) SMEM layout, as cd_tile_solve
+_HIGHEST = jax.lax.Precision.HIGHEST
+# scoped VMEM of the Gram kernel: at T = 512 its double-buffered design
+# block, Gram block and the transposed operand need ~20 MiB, above Mosaic's
+# 16 MiB default (a v5e core has 128 MiB of VMEM)
+_GRAM_VMEM_LIMIT = 48 << 20
 
 
-def _tile_solve(G, g, h, beta, pf, mu, nu, lam1, lam2):
-    """Sequential soft-threshold chain over the T coordinates of one tile —
-    the cd_tile_solve.py kernel body, reused verbatim on the VMEM-resident
-    Gram accumulated by the enclosing fused kernel (Jacobi: dbeta0 = 0)."""
-    T = g.shape[0]
-    lam1v = lam1 * pf
-    den = mu * h + nu + lam2 * pf
-    den_safe = jnp.maximum(den, 1e-30)
+def _matmul_inputs(precision, *xs):
+    """Matmul operands and dot precision for the requested mode: bf16
+    inputs (f32 accumulation) or full-f32 contraction."""
+    if precision == "bf16":
+        return tuple(x.astype(jnp.bfloat16) for x in xs), None
+    return xs, _HIGHEST
 
-    def body(j, carry):
-        g_c, d = carry
-        g_j = jax.lax.dynamic_index_in_dim(g_c, j, keepdims=False)
-        d_j = jax.lax.dynamic_index_in_dim(d, j, keepdims=False)
-        b_j = jax.lax.dynamic_index_in_dim(beta, j, keepdims=False)
-        h_j = jax.lax.dynamic_index_in_dim(h, j, keepdims=False)
-        l1_j = jax.lax.dynamic_index_in_dim(lam1v, j, keepdims=False)
-        den_j = jax.lax.dynamic_index_in_dim(den, j, keepdims=False)
-        dens_j = jax.lax.dynamic_index_in_dim(den_safe, j, keepdims=False)
 
-        num = g_j + mu * h_j * (b_j + d_j) + nu * b_j
-        u = jnp.sign(num) * jnp.maximum(jnp.abs(num) - l1_j, 0.0) / dens_j
-        u = jnp.where(den_j > 0, u, b_j)
-        d_new = u - b_j
-        delta = d_new - d_j
-        G_col = jax.lax.dynamic_slice(G, (0, j), (T, 1))[:, 0]
-        g_c = g_c - mu * delta * G_col
-        d = jax.lax.dynamic_update_index_in_dim(d, d_new, j, axis=0)
-        return g_c, d
-
-    _, d_final = jax.lax.fori_loop(0, T, body, (g, jnp.zeros_like(g)))
-    return d_final
+def _lane_row(v):
+    """(R, 128) → (1, R·128): row-major lane concatenation of the rows —
+    example ``r·128 + l`` of the block lands in lane ``r·128 + l``."""
+    return jnp.concatenate([v[r:r + 1, :] for r in range(v.shape[0])], axis=1)
 
 
 def _stats_gram_solve_kernel(sel_ref, Xt_ref, y_ref, xb_ref, mask_ref,
@@ -98,14 +89,11 @@ def _stats_gram_solve_kernel(sel_ref, Xt_ref, y_ref, xb_ref, mask_ref,
     # link stats for this row block — pure VPU, recomputed per (t, i) step so
     # s/w stay VMEM-resident for the Gram accumulation below; the (R, 128)
     # writes are idempotent across tiles (stats don't depend on t)
-    y = y_ref[...]
-    m = xb_ref[...]
+    loss, s, w = _STATS[family](y_ref[...], xb_ref[...])
     mask = mask_ref[...]
-    loss, s, w = _STATS[family](y, m)
-    loss = loss * mask
+    loss_ref[...] = loss * mask
     s = s * mask
     w = w * mask
-    loss_ref[...] = loss
     s_ref[...] = s
     w_ref[...] = w
 
@@ -117,45 +105,39 @@ def _stats_gram_solve_kernel(sel_ref, Xt_ref, y_ref, xb_ref, mask_ref,
     @pl.when(live)
     def _accumulate():
         X = Xt_ref[0]                      # (block_n, T)
-        wv = w.reshape(-1)                 # (block_n,)
-        sv = s.reshape(-1)
-        wX = X * wv[:, None]
-        if precision == "bf16":
-            Xc = X.astype(jnp.bfloat16)
-            wXc = wX.astype(jnp.bfloat16)
-            svc = sv.astype(jnp.bfloat16)
-        else:
-            Xc, wXc, svc = X, wX, sv
-        G_ref[0] += jax.lax.dot_general(
-            wXc, Xc, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        g_ref[0] += jnp.matmul(svc[None, :], Xc,
-                               preferred_element_type=jnp.float32)[0]
+        # the row weights scale the lanes of Xᵀ: G += Xᵀ diag(w) X
+        (wXt, Xc, sv), prec = _matmul_inputs(
+            precision, X.T * _lane_row(w), X, _lane_row(s))
+        G_ref[0] += jnp.dot(wXt, Xc, precision=prec,
+                            preferred_element_type=jnp.float32)
+        g_ref[0] += jnp.dot(sv, Xc, precision=prec,
+                            preferred_element_type=jnp.float32)
 
     @pl.when(i == nb - 1)
     def _solve():
-        T = g_ref.shape[-1]
-        G = G_ref[0]
-        g = g_ref[0]
+        T = G_ref.shape[-1]
         ii = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
         jj = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-        h = jnp.sum(jnp.where(ii == jj, G, 0.0), axis=1)
-        d_final = _tile_solve(
-            G, g, h, beta_ref[0], penf_ref[0],
-            params_ref[0, MU], params_ref[0, NU],
-            params_ref[0, LAM1], params_ref[0, LAM2])
-        dbeta_ref[0, :] = jnp.where(live, d_final, 0.0)
+        h = jnp.sum(jnp.where(ii == jj, G_ref[0], 0.0), axis=0,
+                    keepdims=True)                               # (1, T)
+        # Jacobi across tiles: every tile's chain starts from dbeta = 0
+        d = solve_chain(
+            lambda j: G_ref[0, pl.ds(j, 1), :], g_ref[0], h, beta_ref[0],
+            penf_ref[0], jnp.zeros_like(h), params_ref[MU], params_ref[NU],
+            params_ref[LAM1], params_ref[LAM2])
+        dbeta_ref[0] = jnp.where(live, d, 0.0)
 
 
 @functools.partial(jax.jit, static_argnames=("family", "block_n", "precision",
                                              "interpret"))
 def stats_gram_solve_pallas(sel, Xt3, y2, xb2, mask2, beta_r, penf_r, params,
-                            *, family, block_n=512, precision="fp32",
+                            *, family, block_n=1024, precision="fp32",
                             interpret=True):
     """Fused launch 1 of the superstep: stats + Gram + tile solve.
 
     sel: (nt + 1,) i32 — live-first tile order then n_live (active-set remap).
-    Xt3: (nt, n_pad, T) tile-major operand, n_pad % block_n == 0.
+    Xt3: (nt, n_pad, T) tile-major operand, n_pad % block_n == 0,
+    block_n % 1024 == 0 (the (8, 128) vector tile).
     y2/xb2/mask2: (R, 128) packed vectors, R * 128 == n_pad.
     beta_r/penf_r: (nt, T); params: (4,) f32 [mu, nu, lam1, lam2].
     Returns (loss2, s2, w2, G_all (nt,T,T), g_all (nt,T), dbeta_r (nt,T)).
@@ -165,17 +147,22 @@ def stats_gram_solve_pallas(sel, Xt3, y2, xb2, mask2, beta_r, penf_r, params,
     br = block_n // 128
     R, C = y2.shape
     f32 = jnp.float32
-    # index maps receive the grid indices first, then the prefetch ref
+    # index maps receive the grid indices first, then the prefetch ref.
+    # Per-tile rows travel as (nt, 1, T) arrays: a (1, T) block spanning the
+    # last two dims is the only row-vector block Mosaic tiles.  A dead tile
+    # (t >= n_live) pins its design block to row block 0, so its predicated-
+    # off steps issue no new DMA.
     vspec = pl.BlockSpec((br, C), lambda t, i, s: (i, 0))
-    tspec = pl.BlockSpec((1, T), lambda t, i, s: (s[t], 0))
+    tspec = pl.BlockSpec((1, 1, T), lambda t, i, s: (s[t], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nt, nb),
         in_specs=[
-            pl.BlockSpec((1, block_n, T), lambda t, i, s: (s[t], i, 0)),
+            pl.BlockSpec((1, block_n, T), lambda t, i, s: (
+                s[t], jnp.where(t < s[nt], i, 0), 0)),
             vspec, vspec, vspec,
             tspec, tspec,
-            pl.BlockSpec((1, 4), lambda t, i, s: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             vspec, vspec, vspec,
@@ -188,21 +175,25 @@ def stats_gram_solve_pallas(sel, Xt3, y2, xb2, mask2, beta_r, penf_r, params,
         jax.ShapeDtypeStruct((R, C), f32),
         jax.ShapeDtypeStruct((R, C), f32),
         jax.ShapeDtypeStruct((nt, T, T), f32),
-        jax.ShapeDtypeStruct((nt, T), f32),
-        jax.ShapeDtypeStruct((nt, T), f32),
+        jax.ShapeDtypeStruct((nt, 1, T), f32),
+        jax.ShapeDtypeStruct((nt, 1, T), f32),
     ]
-    return pl.pallas_call(
+    loss2, s2, w2, G_all, g_all, dbeta_r = pl.pallas_call(
         functools.partial(_stats_gram_solve_kernel, family=family,
                           precision=precision),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_GRAM_VMEM_LIMIT),
         interpret=interpret,
     )(sel.astype(jnp.int32), Xt3.astype(f32), y2.astype(f32),
-      xb2.astype(f32), mask2.astype(f32), beta_r.astype(f32),
-      penf_r.astype(f32), params.astype(f32)[None, :])
+      xb2.astype(f32), mask2.astype(f32),
+      beta_r.astype(f32).reshape(nt, 1, T),
+      penf_r.astype(f32).reshape(nt, 1, T), params.astype(f32))
+    return loss2, s2, w2, G_all, g_all[:, 0], dbeta_r[:, 0]
 
 
-def _margin_ls_kernel(Xt_ref, db_ref, y_ref, xb_ref, mask_ref, alphas_ref,
+def _margin_ls_kernel(alphas_ref, Xt_ref, db_ref, y_ref, xb_ref, mask_ref,
                       xdb_ref, out_ref, *, family, precision):
     i = pl.program_id(0)
     t = pl.program_id(1)
@@ -212,15 +203,14 @@ def _margin_ls_kernel(Xt_ref, db_ref, y_ref, xb_ref, mask_ref, alphas_ref,
     def _init_xdb():
         xdb_ref[...] = jnp.zeros_like(xdb_ref)
 
-    X = Xt_ref[0]                           # (block_n, T)
-    d = db_ref[0]                           # (T,)
-    if precision == "bf16":
-        contrib = jnp.matmul(X.astype(jnp.bfloat16),
-                             d.astype(jnp.bfloat16)[:, None],
-                             preferred_element_type=jnp.float32)[:, 0]
-    else:
-        contrib = jnp.matmul(X, d[:, None])[:, 0]
-    xdb_ref[...] += contrib.reshape(xdb_ref.shape)
+    # (1, T) · (block_n, T)ᵀ → (1, block_n): the margin delta as one lane
+    # row, folded back into the (R, 128) vector block row by row
+    (d, X), prec = _matmul_inputs(precision, db_ref[0], Xt_ref[0])
+    contrib = jax.lax.dot_general(d, X, (((1,), (1,)), ((), ())),
+                                  precision=prec,
+                                  preferred_element_type=jnp.float32)
+    for r in range(xdb_ref.shape[0]):
+        xdb_ref[r:r + 1, :] += contrib[:, r * 128:(r + 1) * 128]
 
     @pl.when(t == nt - 1)
     def _linesearch():
@@ -228,32 +218,19 @@ def _margin_ls_kernel(Xt_ref, db_ref, y_ref, xb_ref, mask_ref, alphas_ref,
         def _init_out():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        y = y_ref[...]
-        xb = xb_ref[...]
-        mask = mask_ref[...]
-        xdb = xdb_ref[...]
-        alphas = alphas_ref[...]            # (1, K)
-        K = alphas.shape[-1]
-
-        def per_alpha(k, acc):
-            a = jax.lax.dynamic_index_in_dim(alphas[0], k, keepdims=False)
-            loss, _, _ = _STATS[family](y, xb + a * xdb)
-            return jax.lax.dynamic_update_index_in_dim(
-                acc, jnp.sum(loss * mask), k, axis=0)
-
-        partial = jax.lax.fori_loop(0, K, per_alpha,
-                                    jnp.zeros((K,), jnp.float32))
-        out_ref[...] += partial[None, :]
+        out_ref[...] += candidate_losses(
+            alphas_ref, y_ref[...], xb_ref[...], xdb_ref[...],
+            mask_ref[...], family=family)
 
 
 @functools.partial(jax.jit, static_argnames=("family", "block_n", "precision",
                                              "interpret"))
 def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, *, family,
-                     block_n=512, precision="fp32", interpret=True):
+                     block_n=1024, precision="fp32", interpret=True):
     """Fused launch 2 of the superstep: margin delta + candidate loss sweep.
 
     Xt3: (nt, n_pad, T); dbeta_r: (nt, T); y2/xb2/mask2: (R, 128) with
-    R * 128 == n_pad; alphas: (K,) with K % 128 == 0 (pad with duplicates).
+    R * 128 == n_pad; alphas: (K,) candidate step sizes.
     Returns (xdb2 (R, 128), losses (K,)).
     """
     nt, n_pad, T = Xt3.shape
@@ -268,15 +245,16 @@ def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, *, family,
                           precision=precision),
         grid=(nb, nt),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_n, T), lambda i, t: (t, i, 0)),
-            pl.BlockSpec((1, T), lambda i, t: (t, 0)),
+            pl.BlockSpec((1, 1, T), lambda i, t: (t, 0, 0)),
             vspec, vspec, vspec,
-            pl.BlockSpec((1, K), lambda i, t: (0, 0)),
         ],
         out_specs=[vspec, pl.BlockSpec((1, K), lambda i, t: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C), f32),
                    jax.ShapeDtypeStruct((1, K), f32)],
         interpret=interpret,
-    )(Xt3.astype(f32), dbeta_r.astype(f32), y2.astype(f32), xb2.astype(f32),
-      mask2.astype(f32), alphas.astype(f32)[None, :])
+    )(alphas.astype(f32), Xt3.astype(f32),
+      dbeta_r.astype(f32).reshape(nt, 1, T), y2.astype(f32),
+      xb2.astype(f32), mask2.astype(f32))
     return out[0], out[1][0]

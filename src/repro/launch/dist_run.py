@@ -288,6 +288,7 @@ def main() -> int:
             forwarded.append(a)
     result = launcher.run_local(args.nprocs, os.path.abspath(__file__),
                                 args=forwarded, timeout_s=args.timeout)
+    print(f"[dist_run] {args.nprocs} local workers ran on JAX_PLATFORMS=cpu")
     print(result.summary())
     if args.trace:
         from repro.obs import trace as obs_trace

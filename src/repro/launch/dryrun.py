@@ -109,7 +109,7 @@ def lower_cell(arch_name: str, shape_name: str, mesh, *, do_compile=True,
     rec["status"] = "ok"
     rec["memory"] = _mem_dict(compiled)
     try:
-        ca = compat.xla_cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         rec["xla_cost_flops"] = float(ca.get("flops", -1.0))
     except Exception:
         rec["xla_cost_flops"] = None

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.data.sparse import SparseCOO
 from repro.kernels import ops
 from repro.obs import metrics as obs_metrics
@@ -78,6 +79,7 @@ class ScoringEngine:
     """
 
     def __init__(self, model: ServableModel, *, outputs=None, backend=None):
+        compile_cache.init()
         self.model = model
         self.family = model.family
         W = np.asarray(model.betas, np.float32)          # (K, p)
@@ -163,6 +165,16 @@ class ScoringEngine:
             obs_trace.instant("serve/compile",
                               args={"shape": list(shape), "kind": kind})
         return fn
+
+    def lower_packed(self, batch: int, nnz: int, *,
+                     kind: str = "response"):
+        """``jax.stages.Lowered`` of the scoring program for (batch, nnz)
+        packed requests — the one launch ``score_packed`` runs."""
+        self._check_kind(kind)
+        slots = jax.ShapeDtypeStruct((batch, nnz), jnp.int32)
+        vals = jax.ShapeDtypeStruct((batch, nnz), jnp.float32)
+        return self._packed_fn((batch, nnz), kind).lower(
+            slots, vals, self._table, self._b0)
 
     def score_packed(self, slots, vals, *, kind: str = "response"):
         """Score pre-packed (B, J) slot/value arrays → (B, K) np.float32.

@@ -1,48 +1,17 @@
-"""Compatibility shims over the moving jax distributed API surface.
-
-The repo targets the modern spelling (``jax.shard_map`` with ``check_vma``,
-``jax.make_mesh(..., axis_types=...)``) but must also run on older jax
-releases where ``shard_map`` still lives in ``jax.experimental`` (with the
-``check_rep`` keyword) and meshes have no axis types.  Every mesh/shard_map
-construction in the repo goes through this module.
-"""
+"""The two mesh spellings every distributed path in the repo goes through:
+``jax.shard_map`` with the replication check off by default, and
+``jax.make_mesh`` with Auto axis types (jax >= 0.9)."""
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.5
-    _shard_map = jax.shard_map
-    _MODERN = True
-except AttributeError:                  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _MODERN = False
-
-# Public flag: the legacy experimental shard_map has known autodiff gaps
-# (e.g. transposing a remat'd body) that callers/tests may need to gate on.
-MODERN_SHARD_MAP = _MODERN
-
-
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` returns a per-device list on older jax
-    and a flat dict on newer; normalize to the dict."""
-    ca = compiled.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the replication-check knob mapped per version."""
-    if _MODERN:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(AxisType.Auto,) * len(axis_names))
-    except (ImportError, AttributeError, TypeError):
-        return jax.make_mesh(axis_shapes, axis_names)
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names))

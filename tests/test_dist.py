@@ -50,6 +50,7 @@ class TestBootstrap:
         assert env["REPRO_DIST_NPROCS"] == "2"
         assert env["REPRO_DIST_COORD"] == "127.0.0.1:1234"
         assert "--xla_force_host_platform_device_count=1" in env["XLA_FLAGS"]
+        assert env["JAX_PLATFORMS"] == "cpu"
 
 
 # ------------------------------------------------------------------- faults

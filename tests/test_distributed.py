@@ -3,7 +3,6 @@ main pytest process keeps seeing exactly 1 device (see conftest)."""
 import pytest
 
 from conftest import run_prog
-from repro.sharding import compat
 
 
 @pytest.mark.slow
@@ -12,9 +11,6 @@ def test_distributed_glm_equivalence():
     assert "DIST_GLM_OK" in out
 
 
-@pytest.mark.skipif(not compat.MODERN_SHARD_MAP,
-                    reason="legacy experimental shard_map cannot transpose "
-                           "the remat'd CE body (fixed in jax >= 0.5)")
 def test_vocab_parallel_ce():
     out = run_prog("dist_ce", devices=8)
     assert "DIST_CE_OK" in out

@@ -3,6 +3,8 @@ across families/designs/observation features, Pallas-kernel-vs-oracle
 interpret parity, active-set-shaped launch bookkeeping, mixed-precision
 accumulation, and the cross-process compilation cache."""
 import os
+import pathlib
+import shutil
 import subprocess
 import sys
 import textwrap
@@ -176,12 +178,17 @@ def test_bf16_tracks_fp32_alpha_sequence():
     assert err <= 0.05 * max(scale, 1.0), (err, scale)
 
 
-def test_compilation_cache_populates_and_hits(tmp_path):
-    """REPRO_COMPILATION_CACHE: a child process populates the persistent
-    cache; an identical second child must add no new entries (pure cache
-    hits on the deserialized executables)."""
+@pytest.mark.parametrize("where", ["env", "checkout"])
+def test_compilation_cache_populates_and_hits(tmp_path, where):
+    """A child process populates the persistent cache; an identical second
+    child must add no new entries (pure cache hits on the deserialized
+    executables).  ``env``: the cache is where JAX_COMPILATION_CACHE_DIR
+    says; ``checkout``: with the variable unset it is the fixed
+    ``<checkout>/.jax_cache`` (here a copy of the package, so the run
+    touches only its own directory)."""
     script = textwrap.dedent("""
         import numpy as np
+        from repro import compile_cache
         from repro.core.dglmnet import DGLMNETConfig
         from repro.core.solver import GLMSolver
         rng = np.random.default_rng(0)
@@ -189,21 +196,28 @@ def test_compilation_cache_populates_and_hits(tmp_path):
         y = rng.choice([-1.0, 1.0], 64).astype(np.float32)
         s = GLMSolver(X, y, config=DGLMNETConfig(tile_size=16, max_outer=3))
         s.fit(lam1=0.3 * s.lambda_max())
-        print("FIT_OK")
+        print("FIT_OK", compile_cache.cache_dir())
     """)
-    env = dict(os.environ, REPRO_COMPILATION_CACHE=str(tmp_path))
-    env["PYTHONPATH"] = os.pathsep.join(
-        [p for p in (env.get("PYTHONPATH"),) if p]
-        + [str(os.path.join(os.path.dirname(__file__), "..", "src"))])
-    r1 = subprocess.run([sys.executable, "-c", script], env=env,
-                        capture_output=True, text=True, timeout=300)
-    assert r1.returncode == 0 and "FIT_OK" in r1.stdout, r1.stderr[-2000:]
-    entries = {p.name for p in tmp_path.rglob("*") if p.is_file()}
-    if not entries:
-        pytest.skip("persistent compilation cache not supported on this "
-                    "jax backend/version")
-    r2 = subprocess.run([sys.executable, "-c", script], env=env,
-                        capture_output=True, text=True, timeout=300)
-    assert r2.returncode == 0 and "FIT_OK" in r2.stdout, r2.stderr[-2000:]
-    entries2 = {p.name for p in tmp_path.rglob("*") if p.is_file()}
-    assert entries2 == entries, entries2 - entries
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    if where == "env":
+        cache = tmp_path / "cache"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(cache)
+    else:
+        shutil.copytree(src / "repro", tmp_path / "src" / "repro",
+                        ignore=shutil.ignore_patterns("__pycache__"))
+        src = tmp_path / "src"
+        cache = tmp_path / ".jax_cache"
+    env["PYTHONPATH"] = str(src)
+
+    def run():
+        r = subprocess.run([sys.executable, "-c", script], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0 and "FIT_OK" in r.stdout, r.stderr[-2000:]
+        assert r.stdout.split()[-1] == str(cache), r.stdout
+        return {p.name for p in cache.rglob("*") if p.is_file()}
+
+    entries = run()
+    assert entries
+    assert run() == entries

@@ -161,3 +161,19 @@ else:
     def test_tile_solve_property_sweep(seed, T, lam1, mu):
         # fixed-case fallback when hypothesis is not installed
         _tile_solve_property(seed, T, lam1, mu)
+
+
+def test_oracle_dispatches_are_recorded_on_tpu(monkeypatch):
+    """On a TPU every dispatch that lands on the jnp oracle is recorded
+    with its reason while an ``oracle_trace`` is active (the chip smoke
+    lists them); nothing is recorded outside one."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    y, xb = jnp.ones((8,)), jnp.zeros((8,))
+    ops.glm_stats(y, xb, "logistic", backend="ref")
+    with ops.oracle_trace() as rec:
+        ops.glm_stats(y, xb, "logistic", backend="ref")
+        monkeypatch.setenv("REPRO_KERNEL_BACKEND", "ref")
+        ops.alpha_search(y, xb, xb, jnp.ones((2,)), "logistic")
+    assert dict(rec) == {
+        ("glm_stats", "backend='ref' requested"): 1,
+        ("alpha_search", "REPRO_KERNEL_BACKEND=ref"): 1}

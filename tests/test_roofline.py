@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.roofline.hlo import analyze_hlo
-from repro.sharding.compat import xla_cost_analysis
 
 
 def test_scan_trip_count_exact():
@@ -22,7 +21,7 @@ def test_scan_trip_count_exact():
     expected = 12 * 2 * 256 ** 3
     assert abs(st.flops - expected) / expected < 0.01
     # XLA's own analysis undercounts the loop — make sure we beat it
-    assert st.flops > 5 * xla_cost_analysis(c)["flops"]
+    assert st.flops > 5 * c.cost_analysis()["flops"]
 
 
 def test_backward_scan_counted():
@@ -48,7 +47,7 @@ def test_loop_free_matches_cost_analysis():
     a = jax.ShapeDtypeStruct((256, 256), jnp.float32)
     c = jax.jit(plain).lower(a, a).compile()
     st = analyze_hlo(c.as_text())
-    xla = xla_cost_analysis(c)["flops"]
+    xla = c.cost_analysis()["flops"]
     assert abs(st.flops - xla) / xla < 0.02
 
 

@@ -260,19 +260,16 @@ def test_coo_to_requests_handles_empty_rows():
 # -------------------------------------------------------------- kernel
 
 
-@pytest.mark.parametrize("family", FAMILIES)
-@pytest.mark.parametrize("kind", ("link", "response"))
-def test_predict_tile_kernel_matches_oracle(family, kind):
+def _predict_tile_parity(family, kind, A, L, B, J, seed, scale=1.0):
     import jax.numpy as jnp
 
     from repro.kernels import ops, ref
-    rng = np.random.default_rng(5)
-    A, L, B, J = 19, 3, 11, 7          # deliberately unaligned shapes
+    rng = np.random.default_rng(seed)
     table = np.zeros((A + 1, L), np.float32)
-    table[:-1] = rng.normal(size=(A, L))
+    table[:-1] = rng.normal(size=(A, L)) * scale
     slots = rng.integers(0, A + 1, size=(B, J)).astype(np.int32)
     vals = rng.normal(size=(B, J)).astype(np.float32)
-    b0 = rng.normal(size=L).astype(np.float32)
+    b0 = (rng.normal(size=L) * scale).astype(np.float32)
     o = ref.predict_tile(jnp.asarray(slots), jnp.asarray(vals),
                          jnp.asarray(table), jnp.asarray(b0).reshape(1, -1),
                          family, kind=kind)
@@ -281,6 +278,25 @@ def test_predict_tile_kernel_matches_oracle(family, kind):
                          backend="pallas")
     assert k.shape == (B, L)
     np.testing.assert_allclose(np.asarray(k), np.asarray(o), atol=1e-5)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("kind", ("link", "response"))
+def test_predict_tile_kernel_matches_oracle(family, kind):
+    _predict_tile_parity(family, kind, A=19, L=3, B=11, J=7, seed=5)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("kind", ("link", "response"))
+def test_predict_tile_kernel_multi_block_table_matches_oracle(
+        family, kind, monkeypatch):
+    """A table of several row blocks: 300 outputs pad to 384 lanes, and a
+    20-row block budget rounds down to 16 rows, so the 46 table rows span
+    three blocks that the 99 (slot, value) pairs all reach."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_TABLE_BLOCK_BYTES", 20 * 4 * 384)
+    _predict_tile_parity(family, kind, A=45, L=300, B=11, J=9, seed=7,
+                         scale=0.2)
 
 
 def test_predict_tile_unknown_family_falls_back_to_oracle():
