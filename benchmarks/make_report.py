@@ -38,9 +38,6 @@ BENCH_COLUMNS = {
                         "supersteps", "wall_s", "wall_per_superstep_s",
                         "recovery_vs_alb_off", "f_final", "nnz",
                         "final_budgets", "node_speeds", "compute_speeds"],
-    "obs": ["case", "n_spans", "span_names", "top_span",
-            "top_span_total_ms", "conv_events", "supersteps",
-            "mean_step_us", "final_f", "disabled_span_overhead_us"],
     "ingest_bench": ["case", "format", "rows", "features", "chunks",
                      "nnz_total", "file_mb", "scan_s", "pass_s",
                      "rows_per_s", "nnz_per_s", "hash_dim", "supersteps",

@@ -338,8 +338,11 @@ class GLMSolver:
         # host-side sweep launch bookkeeping (active-set-shaped launches,
         # DESIGN.md §8): tiles the CD sweep actually processed vs skipped
         # because every coordinate was screened out.  In-memory fits only.
+        # The λ-path loop counts the λ points it finished and the ``_run``
+        # calls (KKT rounds) it made for them.
         self.launch_stats = {"supersteps": 0, "sweep_tile_launches": 0,
-                             "sweep_tiles_skipped": 0}
+                             "sweep_tiles_skipped": 0, "lambdas": 0,
+                             "kkt_rounds": 0}
         # convergence event stream (repro.obs, DESIGN.md §12): auto-opened
         # next to the trace shards when tracing targets a directory, or
         # attached explicitly via set_convergence_stream().
@@ -911,22 +914,23 @@ class GLMSolver:
                        for m in self.dist_info["local_columns"]))
 
     def _dispatch_superstep(self, weights_dev, lams, active_dev, state):
-        """One superstep with the distributed hooks around it (DESIGN.md
-        §9): per-superstep budgets, fault-plan work injection, and
-        telemetry recording.  Without telemetry/faults this is exactly the
-        bare compiled-superstep call (plus an obs span that is a cached
-        no-op when tracing is disabled)."""
+        """One superstep, from its dispatch to its metrics on the host,
+        with the distributed hooks around it (DESIGN.md §9): per-superstep
+        budgets, fault-plan work injection, and telemetry recording.
+        Returns (state, host metrics).  Without telemetry/faults this is
+        the bare compiled-superstep call and its one sync.  Either way one
+        ``solver/superstep`` span covers the dispatch and the sync, with
+        the sync's wait nested in it as ``solver/sync``."""
         budgets = self._budgets()
         if self._telemetry is None and self._faults is None:
             with obs_trace.span("solver/superstep") as sp:
-                out = self._superstep(self._Xs, self._ys, weights_dev,
-                                      self._offsets, budgets, lams,
-                                      active_dev, self._penf, state)
-            # host-side dispatch span: the per-iteration device_get in
-            # _run provides the sync, so no extra block here (SYNC001)
+                state, m = self._superstep(self._Xs, self._ys, weights_dev,
+                                           self._offsets, budgets, lams,
+                                           active_dev, self._penf, state)
+                mh = self._fetch_metrics(m)
             self._last_step_us = sp.elapsed_us or None
             self._last_phase_us = None
-            return out
+            return state, mh
         step_no = self._superstep_no
         self._superstep_no += 1
         pid = 0 if self.dist_info is None else self.dist_info["process_id"]
@@ -951,9 +955,11 @@ class GLMSolver:
             state, m = self._superstep(self._Xs, self._ys, weights_dev,
                                        self._offsets, budgets, lams,
                                        active_dev, self._penf, state)
+            if self._telemetry is not None:
+                jax.block_until_ready(state)
+                measured = time.perf_counter() - t0
+            mh = self._fetch_metrics(m)
         if self._telemetry is not None:
-            jax.block_until_ready(state)
-            measured = time.perf_counter() - t0
             # under a fault plan the injected work IS the node's local-phase
             # seconds; raw wall-clock around a globally-synchronized SPMD
             # program would fold in collective-wait time (every process
@@ -977,7 +983,15 @@ class GLMSolver:
         else:
             self._last_step_us = None
             self._last_phase_us = None
-        return state, m
+        return state, mh
+
+    @staticmethod
+    def _fetch_metrics(m) -> dict:
+        """ONE device→host sync per superstep: fetching the metrics dict
+        whole lets every scalar ride a single transfer instead of blocking
+        the dispatch pipe per key (lint rule SYNC001)."""
+        with obs_trace.span("solver/sync"):
+            return jax.device_get(m)
 
     def _compose_phases(self, work_phases: dict) -> dict:
         """Fault-plan phase attribution composed with the registered probe
@@ -1047,22 +1061,29 @@ class GLMSolver:
             phase_us=self._last_phase_us if phase_us is None else phase_us)
 
     def _run(self, state: FitState, lam1: float, lam2: float, *,
-             weights=None, active=None, max_outer=None, tol=None,
-             verbose=False, ckpt_manager=None, ckpt_every: int = 10,
-             ckpt_every_chunks: Optional[int] = None):
+             kkt_round: Optional[int] = None, **kwargs):
         """Drive supersteps at fixed (λ1, λ2) until the objective plateaus.
 
-        Returns (state, history, n_iter, converged).  ``active`` is a host
+        Returns (state, history, n_iter, converged).  Keyword arguments
+        (``weights``, ``active``, ``max_outer``, ``tol``, ``verbose``,
+        checkpointing) go to the in-memory or the streaming loop.  One
+        ``solver/run`` span covers the call; ``kkt_round`` (the λ-path
+        loop's KKT round at its λ, 0 first) is recorded on it.
+        """
+        loop = self._run_streaming if self._streaming else \
+            self._run_in_memory
+        with obs_trace.span("solver/run", args=None if kkt_round is None
+                            else {"round": kkt_round}):
+            return loop(state, lam1, lam2, **kwargs)
+
+    def _run_in_memory(self, state: FitState, lam1: float, lam2: float, *,
+                       weights=None, active=None, max_outer=None, tol=None,
+                       verbose=False, ckpt_manager=None, ckpt_every: int = 10,
+                       ckpt_every_chunks: Optional[int] = None):
+        """The in-memory outer loop of ``_run``.  ``active`` is a host
         (p_tot,) 0/1 mask in packed column order (None = all coordinates);
         ``weights`` a placed (n_tot,) row-weight vector (None = the session
-        weights — CV fold fits pass fold-masked vectors).
-        """
-        if self._streaming:
-            return self._run_streaming(
-                state, lam1, lam2, weights=weights, active=active,
-                max_outer=max_outer, tol=tol, verbose=verbose,
-                ckpt_manager=ckpt_manager, ckpt_every=ckpt_every,
-                ckpt_every_chunks=ckpt_every_chunks)
+        weights — CV fold fits pass fold-masked vectors)."""
         cfg = self.config
         max_outer = cfg.max_outer if max_outer is None else int(max_outer)
         tol = cfg.tol if tol is None else float(tol)
@@ -1112,18 +1133,15 @@ class GLMSolver:
             f_prev = md.get("f_prev", np.inf)
             start_it = int(md["next_it"])
         for it in range(start_it, max_outer + 1):
-            state, m = self._dispatch_superstep(weights_dev, lams,
-                                                active_dev, state)
+            state, mh = self._dispatch_superstep(weights_dev, lams,
+                                                 active_dev, state)
             self.launch_stats["supersteps"] += 1
             self.launch_stats["sweep_tile_launches"] += \
                 live_tiles if shaped else total_tiles
             if shaped:
                 self.launch_stats["sweep_tiles_skipped"] += \
                     total_tiles - live_tiles
-            # ONE device→host sync per superstep: fetching the metrics dict
-            # whole lets every scalar ride a single transfer instead of
-            # blocking the dispatch pipe per key (lint rule SYNC001).
-            mh = jax.device_get(m)
+            # lint: allow SYNC001 — mh is the host copy fetched in dispatch
             f = float(mh["f"])
             for k in history:
                 history[k].append(float(mh[k]))
@@ -1563,80 +1581,90 @@ class GLMSolver:
         g_warm = None           # gradient at the warm iterate, if known
         for k in range(start_k, K):
             lam1 = float(lambdas[k])
-            # fresh trust region per λ; warm β / margins carry over
-            state = state._replace(
-                mu=self._place_scalar(cfg.mu_init, np.float32),
-                step=self._place_scalar(0, np.int32))
-            if screen:
-                # sequential strong rule (Tibshirani et al. 2012):
-                # |g_j| = |[Xᵀ s(β_{k-1})]_j| ≥ pf_j (2λ_k − λ_{k-1}) — plus
-                # every currently-active and every unpenalized coordinate;
-                # the previous λ's final KKT gradient IS the gradient at
-                # this warm iterate, so reuse it
-                g = self._grad_state(state, weights) if g_warm is None \
-                    else g_warm
-                thresh = 2.0 * lam1 - (lam_prev if lam_prev is not None
-                                       else lam1)
-                active = (np.abs(g) >= pf * thresh - 1e-12) | \
-                    (self._host(state.beta) != 0.0) | unpen
-                it_k = 0
-                for _ in range(8):
-                    # convergence-stream context: where on the path we
-                    # are, how hard the strong rule screened, and what
-                    # the last KKT check found (None before the first)
-                    self._conv_ctx = {
-                        "lam_index": k,
-                        "screened": int(active.size - active.sum()),
-                        "kkt_violations": self._conv_ctx.get(
-                            "kkt_violations")
-                        if self._conv_ctx.get("lam_index") == k else None}
-                    state, hist, it_round, conv_k = self._run(
-                        state, lam1, lam2, weights=weights, active=active,
+            with obs_trace.span("solver/lambda", args={"k": k, "lam1": lam1}):
+                # fresh trust region per λ; warm β / margins carry over
+                state = state._replace(
+                    mu=self._place_scalar(cfg.mu_init, np.float32),
+                    step=self._place_scalar(0, np.int32))
+                if screen:
+                    with obs_trace.span("solver/screen"):
+                        # sequential strong rule (Tibshirani et al. 2012):
+                        # |g_j| = |[Xᵀ s(β_{k-1})]_j| ≥ pf_j (2λ_k − λ_{k-1})
+                        # — plus every currently-active and every
+                        # unpenalized coordinate; the previous λ's final
+                        # KKT gradient IS the gradient at this warm
+                        # iterate, so reuse it
+                        g = self._grad_state(state, weights) \
+                            if g_warm is None else g_warm
+                        thresh = 2.0 * lam1 - (lam_prev if lam_prev is not None
+                                               else lam1)
+                        active = (np.abs(g) >= pf * thresh - 1e-12) | \
+                            (self._host(state.beta) != 0.0) | unpen
+                    it_k = 0
+                    for r in range(8):
+                        # convergence-stream context: where on the path we
+                        # are, how hard the strong rule screened, and what
+                        # the last KKT check found (None before the first)
+                        self._conv_ctx = {
+                            "lam_index": k,
+                            "screened": int(active.size - active.sum()),
+                            "kkt_violations": self._conv_ctx.get(
+                                "kkt_violations")
+                            if self._conv_ctx.get("lam_index") == k else None}
+                        state, hist, it_round, conv_k = self._run(
+                            state, lam1, lam2, kkt_round=r, weights=weights,
+                            active=active, max_outer=max_outer, tol=tol,
+                            verbose=verbose)
+                        self.launch_stats["kkt_rounds"] += 1
+                        it_k += it_round
+                        with obs_trace.span("solver/kkt"):
+                            # KKT post-check on the FULL gradient: a
+                            # screened-out coordinate (β_j = 0) is truly
+                            # optimal iff |g_j| ≤ λ1 pf_j
+                            g = self._grad_state(state, weights)
+                            viol = (~active) & (
+                                np.abs(g) > pf * lam1 * (1.0 + kkt_slack)
+                                + 1e-7)
+                        self._conv_ctx["kkt_violations"] = int(viol.sum())
+                        if not viol.any():
+                            break
+                        active |= viol
+                    g_warm = g
+                else:
+                    self._conv_ctx = {"lam_index": k}
+                    state, hist, it_k, conv_k = self._run(
+                        state, lam1, lam2, kkt_round=0, weights=weights,
                         max_outer=max_outer, tol=tol, verbose=verbose)
-                    it_k += it_round
-                    # KKT post-check on the FULL gradient: a screened-out
-                    # coordinate (β_j = 0) is truly optimal iff
-                    # |g_j| ≤ λ1 pf_j
-                    g = self._grad_state(state, weights)
-                    viol = (~active) & (np.abs(g) >
-                                        pf * lam1 * (1.0 + kkt_slack) + 1e-7)
-                    self._conv_ctx["kkt_violations"] = int(viol.sum())
-                    if not viol.any():
-                        break
-                    active |= viol
-                g_warm = g
-            else:
-                self._conv_ctx = {"lam_index": k}
-                state, hist, it_k, conv_k = self._run(
-                    state, lam1, lam2, weights=weights, max_outer=max_outer,
-                    tol=tol, verbose=verbose)
-            betas_packed[k] = self._host(state.beta)
-            if hist["f"]:
-                f[k] = hist["f"][-1]
-                nnz[k] = int(hist["nnz"][-1])
-            n_iters[k] = it_k
-            converged[k] = conv_k
-            if val_dev is not None:
-                val_dev[k] = self._deviance_state(state, ew_dev) / ew_sum \
-                    if ew_sum > 0 else np.nan
-            lam_prev = lam1
-            if verbose:
-                print(f"[path {k + 1}/{K}] lam1={lam1:.6g} f={f[k]:.8f} "
-                      f"nnz={nnz[k]} iters={it_k}")
-            if ckpt_manager is not None:
-                ckpt_manager.save(
-                    k + 1,
-                    {"beta": state.beta, "xb": state.xb, "mu": state.mu,
-                     "path_betas": betas_packed},
-                    metadata={"design_layout": self._design_layout,
-                              "path": {"next_k": k + 1,
-                                       "lambdas": lambdas.tolist(),
-                                       "lam2": lam2,
-                                       "f": f[:k + 1].tolist(),
-                                       "nnz": nnz[:k + 1].tolist(),
-                                       "n_iters": n_iters[:k + 1].tolist(),
-                                       "converged":
-                                           converged[:k + 1].tolist()}})
+                    self.launch_stats["kkt_rounds"] += 1
+                betas_packed[k] = self._host(state.beta)
+                if hist["f"]:
+                    f[k] = hist["f"][-1]
+                    nnz[k] = int(hist["nnz"][-1])
+                n_iters[k] = it_k
+                converged[k] = conv_k
+                if val_dev is not None:
+                    val_dev[k] = self._deviance_state(state, ew_dev) / ew_sum \
+                        if ew_sum > 0 else np.nan
+                lam_prev = lam1
+                if verbose:
+                    print(f"[path {k + 1}/{K}] lam1={lam1:.6g} f={f[k]:.8f} "
+                          f"nnz={nnz[k]} iters={it_k}")
+                if ckpt_manager is not None:
+                    ckpt_manager.save(
+                        k + 1,
+                        {"beta": state.beta, "xb": state.xb, "mu": state.mu,
+                         "path_betas": betas_packed},
+                        metadata={"design_layout": self._design_layout,
+                                  "path": {"next_k": k + 1,
+                                           "lambdas": lambdas.tolist(),
+                                           "lam2": lam2,
+                                           "f": f[:k + 1].tolist(),
+                                           "nnz": nnz[:k + 1].tolist(),
+                                           "n_iters":
+                                               n_iters[:k + 1].tolist(),
+                                           "converged":
+                                               converged[:k + 1].tolist()}})
+            self.launch_stats["lambdas"] += 1
         if ckpt_manager is not None:
             ckpt_manager.wait()
         self._conv_ctx = {}
@@ -1676,14 +1704,16 @@ class GLMSolver:
         """
         cfg = self.config
         lam2 = cfg.lam2 if lam2 is None else float(lam2)
-        lambdas = self._make_grid(lambdas, n_lambdas, lam_ratio)
-        betas_packed, f, nnz, n_iters, converged, _, state = self._path_impl(
-            lambdas, lam2, screen=screen, kkt_slack=kkt_slack,
-            max_outer=max_outer, tol=tol, verbose=verbose,
-            ckpt_manager=ckpt_manager)
-        self._state = state
-        result = self._path_result(lambdas, lam2, betas_packed, f, nnz,
-                                   n_iters, converged)
+        with obs_trace.span("solver/path"):
+            lambdas = self._make_grid(lambdas, n_lambdas, lam_ratio)
+            betas_packed, f, nnz, n_iters, converged, _, state = \
+                self._path_impl(lambdas, lam2, screen=screen,
+                                kkt_slack=kkt_slack, max_outer=max_outer,
+                                tol=tol, verbose=verbose,
+                                ckpt_manager=ckpt_manager)
+            self._state = state
+            result = self._path_result(lambdas, lam2, betas_packed, f, nnz,
+                                       n_iters, converged)
         if len(lambdas):
             self.beta_ = result.betas[-1]
             self.intercept_ = float(result.intercepts[-1]) \

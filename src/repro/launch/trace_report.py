@@ -11,11 +11,11 @@ three artifact families in one directory:
     stream.
 
 This CLI digests them into the terminal summary an operator wants BEFORE
-opening Perfetto: top spans by total time, per-process phase attribution
-(which node is slow, and in WHICH phase — compute vs network is the
-straggler-diagnosis question), merged metrics, and the convergence tail.
-``--bench`` additionally writes a ``results/benchmarks/obs.json`` row
-(rendered by ``benchmarks/make_report.py``).
+opening Perfetto: top spans by self time (a span's duration less what
+its child spans on the same thread cover, so nested spans never count
+twice), per-process phase attribution (which node is slow, and in WHICH
+phase — compute vs network is the straggler-diagnosis question), merged
+metrics, and the convergence tail.
 """
 from __future__ import annotations
 
@@ -23,15 +23,24 @@ import argparse
 import json
 import pathlib
 import sys
-import time
 
 from repro.obs import convergence as conv_lib
 from repro.obs import metrics as metrics_lib
 from repro.timing import percentiles
 
-# span-name prefix -> diagnosis phase bucket (everything else: "other")
+# span name -> diagnosis phase bucket (everything else: "other"); a span
+# is charged its self time.  ``solver/superstep`` and its ``solver/sync``
+# are the superstep from dispatch to metrics on the host; ``solver/run``'s
+# self time is the outer loop's bookkeeping between supersteps; the λ-path
+# loop's spans hold λ set-up, screening and the KKT checks.
 _PHASE_OF_SPAN = {
     "solver/superstep": "compute",
+    "solver/sync": "compute",
+    "solver/run": "outer_loop",
+    "solver/path": "lambda_path",
+    "solver/lambda": "lambda_path",
+    "solver/screen": "lambda_path",
+    "solver/kkt": "lambda_path",
     "solver/stream_stats": "compute",
     "solver/stream_sweep": "compute",
     "solver/stream_line_search": "compute",
@@ -46,18 +55,24 @@ _PHASE_OF_SPAN = {
 
 
 def _iter_spans(trace: dict):
-    """Yield (pid, tid, name, dur_us) for every balanced B/E pair."""
+    """Yield (pid, tid, name, dur_us, self_us) for every balanced B/E
+    pair.  ``self_us`` is the duration less what the span's direct
+    children on the same thread cover (they nest, so their durations
+    add)."""
     stacks: dict = {}
     for ev in trace.get("traceEvents", []):
         ph = ev.get("ph")
         if ph == "B":
-            stacks.setdefault((ev["pid"], ev["tid"]), []).append(ev)
+            stacks.setdefault((ev["pid"], ev["tid"]), []).append([ev, 0.0])
         elif ph == "E":
             st = stacks.get((ev["pid"], ev["tid"]))
             if st:
-                b = st.pop()
-                yield (ev["pid"], ev["tid"], b["name"],
-                       max(ev["ts"] - b["ts"], 0.0))
+                b, children = st.pop()
+                dur = max(ev["ts"] - b["ts"], 0.0)
+                if st:
+                    st[-1][1] += dur
+                yield (ev["pid"], ev["tid"], b["name"], dur,
+                       max(dur - children, 0.0))
 
 
 def load_spans(dir: pathlib.Path):
@@ -75,24 +90,26 @@ def load_spans(dir: pathlib.Path):
 
 
 def span_table(spans) -> list:
-    """Per-name totals sorted by total time: the 'where did the wall go'
-    table."""
+    """Per-name totals sorted by self time: the 'where did the wall go'
+    table (the self times add up to the time the outermost spans
+    cover, each instant once)."""
     by_name: dict = {}
-    for _, _, name, dur in spans:
-        by_name.setdefault(name, []).append(dur)
+    for _, _, name, dur, self_us in spans:
+        by_name.setdefault(name, []).append((dur, self_us))
     rows = []
-    for name, durs in by_name.items():
-        pct = percentiles(durs)
-        rows.append({"span": name, "count": len(durs),
-                     "total_ms": round(sum(durs) / 1e3, 3),
+    for name, pairs in by_name.items():
+        pct = percentiles([d for d, _ in pairs])
+        rows.append({"span": name, "count": len(pairs),
+                     "self_ms": round(sum(s for _, s in pairs) / 1e3, 3),
+                     "total_ms": round(sum(d for d, _ in pairs) / 1e3, 3),
                      "p50_us": round(pct["p50"], 1),
                      "p99_us": round(pct["p99"], 1)})
-    rows.sort(key=lambda r: -r["total_ms"])
+    rows.sort(key=lambda r: -r["self_ms"])
     return rows
 
 
 def phase_attribution(dir: pathlib.Path, spans) -> dict:
-    """Per-process µs by diagnosis phase.
+    """Per-process µs by diagnosis phase, each span charged its self time.
 
     The convergence streams carry the solver's OWN per-phase attribution
     (``phase_us`` — fault-plan/probe-derived, including "network"/"io"
@@ -100,10 +117,10 @@ def phase_attribution(dir: pathlib.Path, spans) -> dict:
     checkpoint/serve side.  A node whose excess shows up under compute is
     an ALB problem; under network/io it is not (DESIGN.md §12)."""
     per_pid: dict = {}
-    for pid, _, name, dur in spans:
+    for pid, _, name, _, self_us in spans:
         bucket = _PHASE_OF_SPAN.get(name, "other")
         per_pid.setdefault(pid, {})[bucket] = \
-            per_pid.setdefault(pid, {}).get(bucket, 0.0) + dur
+            per_pid.setdefault(pid, {}).get(bucket, 0.0) + self_us
     for conv in sorted(dir.glob("convergence_*.jsonl")):
         pid = conv.stem.split("_", 1)[1]
         pid = int(pid) if pid.isdigit() else pid
@@ -165,12 +182,12 @@ def summarize(dir) -> dict:
 def _print_summary(s: dict):
     print(f"== trace report: {s['dir']} ({s['n_spans']} spans) ==")
     if s["spans"]:
-        print("\n-- top spans (by total time) --")
-        print(f"{'span':32} {'count':>7} {'total_ms':>10} "
+        print("\n-- top spans (by self time) --")
+        print(f"{'span':32} {'count':>7} {'self_ms':>10} {'total_ms':>10} "
               f"{'p50_us':>9} {'p99_us':>9}")
         for r in s["spans"][:12]:
-            print(f"{r['span']:32} {r['count']:>7} {r['total_ms']:>10} "
-                  f"{r['p50_us']:>9} {r['p99_us']:>9}")
+            print(f"{r['span']:32} {r['count']:>7} {r['self_ms']:>10} "
+                  f"{r['total_ms']:>10} {r['p50_us']:>9} {r['p99_us']:>9}")
     if s["phase_attribution"]:
         print("\n-- per-process phase attribution (µs) --")
         for pid, phases in s["phase_attribution"].items():
@@ -198,51 +215,11 @@ def _print_summary(s: dict):
               f"mean_step_us={c['mean_step_us']}")
 
 
-def _disabled_overhead_us(n: int = 1000) -> float:
-    """Median cost of one DISABLED span (the null tracer is disabled mode
-    whatever the module tracer's state) — the ISSUE's <5µs contract,
-    re-measured on the machine that generates the committed row."""
-    from repro.obs import trace as trace_lib
-    null = trace_lib._NULL_TRACER
-    samples = []
-    for _ in range(n):
-        # lint: allow OBS001 — this IS the measurement of the span machinery
-        t0 = time.perf_counter_ns()
-        with null.span("bench/noop"):
-            pass
-        samples.append((time.perf_counter_ns() - t0) / 1e3)
-    return round(percentiles(samples)["p50"], 3)
-
-
-def bench_row(s: dict) -> dict:
-    """The committed results/benchmarks/obs.json figure (make_report)."""
-    c = s.get("convergence") or {}
-    top = s["spans"][0] if s["spans"] else {}
-    return {
-        "figure": "obs",
-        "rows": [{
-            "case": "traced_fit",
-            "n_spans": s["n_spans"],
-            "span_names": len(s["spans"]),
-            "top_span": top.get("span"),
-            "top_span_total_ms": top.get("total_ms"),
-            "conv_events": c.get("n_events"),
-            "supersteps": c.get("supersteps"),
-            "mean_step_us": c.get("mean_step_us"),
-            "final_f": c.get("final_f"),
-            "disabled_span_overhead_us": _disabled_overhead_us(),
-        }],
-    }
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("dir", help="trace/metrics/convergence directory")
     ap.add_argument("--json", default="",
                     help="also write the full summary as JSON here")
-    ap.add_argument("--bench", default="",
-                    help="write a results/benchmarks-style obs.json row "
-                    "here (the committed figure input)")
     args = ap.parse_args(argv)
     d = pathlib.Path(args.dir)
     if not d.is_dir():
@@ -252,10 +229,6 @@ def main(argv=None) -> int:
     _print_summary(s)
     if args.json:
         pathlib.Path(args.json).write_text(json.dumps(s, indent=2))
-    if args.bench:
-        out = pathlib.Path(args.bench)
-        out.parent.mkdir(parents=True, exist_ok=True)
-        out.write_text(json.dumps(bench_row(s), indent=2))
     return 0
 
 

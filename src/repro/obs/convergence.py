@@ -32,8 +32,11 @@ golden-key-pinned (``tests/test_obs.py``), so downstream tooling
                     cumulative launch bookkeeping
                     (``GLMSolver.launch_stats``, fed by the kernel
                     dispatchers' ``ops.record_launch``)
-  step_us           wall µs of this superstep (blocked; None when the
-                    solver is not timing)
+  step_us           wall µs of this superstep: from its dispatch to its
+                    metrics on the host (the ``solver/superstep`` span),
+                    telemetry's measured local seconds when telemetry
+                    runs, the streaming passes' spans when streaming;
+                    None when the solver is not timing
   phase_us          per-phase µs split of ``step_us`` via the registered
                     phase fractions (``set_phase_fractions``), or None
 

@@ -8,6 +8,7 @@ import sys
 import threading
 import time
 
+import numpy as np
 import pytest
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
@@ -238,8 +239,6 @@ class TestConvergence:
     def test_solver_emits_stream(self, tmp_path):
         """A real (tiny) fit wired to a stream yields one event per outer
         iteration with live objective/active-set numbers."""
-        import numpy as np
-
         from repro.core.dglmnet import DGLMNETConfig
         from repro.core.solver import GLMSolver
 
@@ -282,36 +281,181 @@ class TestTraceReport:
         r.save(tmp_path / "metrics_0.json")
 
     def test_summarize_and_bench_row(self, tmp_path):
+        """The summary charges each span its self time: nested spans do
+        not count twice in the span table or the phase attribution."""
         from repro.launch import trace_report
 
         self._populate(tmp_path)
+        tr = trace.Tracer(tmp_path, pid=2, jax_annotations=False)
+        # run 0..10 ms holds a superstep 1..7 ms, which holds its sync
+        # 3..6 ms; then the KKT check 11..12 ms inside the λ 0..12 ms
+        for ph, ms, name in (("B", 0, "solver/lambda"), ("B", 0, "solver/run"),
+                             ("B", 1, "solver/superstep"),
+                             ("B", 3, "solver/sync"), ("E", 6, "solver/sync"),
+                             ("E", 7, "solver/superstep"),
+                             ("E", 10, "solver/run"), ("B", 11, "solver/kkt"),
+                             ("E", 12, "solver/kkt"),
+                             ("E", 12, "solver/lambda")):
+            tr._events.append((ph, ms * 1_000_000, 1, name, None))
+        tr.save()
         s = trace_report.summarize(tmp_path)
-        assert s["n_spans"] == 2
-        [row] = s["spans"]
-        assert row["span"] == "solver/superstep" and row["count"] == 2
-        assert row["total_ms"] == pytest.approx(5.0, rel=0.01)
+        assert s["n_spans"] == 7
+        rows = {r["span"]: r for r in s["spans"]}
+        assert rows["solver/superstep"]["count"] == 3
+        # pids 0 and 1: 1 + 4 ms, no children; pid 2: 6 ms less its sync
+        assert rows["solver/superstep"]["total_ms"] == \
+            pytest.approx(11.0, rel=0.01)
+        assert rows["solver/superstep"]["self_ms"] == \
+            pytest.approx(8.0, rel=0.01)
+        assert rows["solver/run"]["total_ms"] == pytest.approx(10.0)
+        assert rows["solver/run"]["self_ms"] == pytest.approx(4.0)
+        assert rows["solver/lambda"]["self_ms"] == pytest.approx(1.0)
+        # self times add up to what the outermost spans cover: 1 + 4 ms
+        # (pids 0 and 1) and 12 ms (pid 2)
+        assert sum(r["self_ms"] for r in rows.values()) == \
+            pytest.approx(5.0 + 12.0)
+        assert s["spans"][0]["span"] == "solver/superstep"
         attrib = s["phase_attribution"]
         assert attrib["0"]["compute"] == pytest.approx(1_000.0)
         assert attrib["1"]["compute"] == pytest.approx(4_000.0)
         assert attrib["0"]["solver.sweep"] == pytest.approx(700.0)
+        assert attrib["2"] == {"compute": pytest.approx(6_000.0),
+                               "outer_loop": pytest.approx(4_000.0),
+                               "lambda_path": pytest.approx(2_000.0)}
         assert s["metrics"]["counters"]["io.chunk_cache.hit"] == 3.0
         assert s["convergence"]["n_events"] == 1
         assert s["convergence"]["final_f"] == 2.0
-        bench = trace_report.bench_row(s)
-        assert bench["figure"] == "obs"
-        [brow] = bench["rows"]
-        assert brow["top_span"] == "solver/superstep"
-        assert brow["conv_events"] == 1
 
     def test_cli_writes_outputs(self, tmp_path, capsys):
         from repro.launch import trace_report
 
         self._populate(tmp_path)
         out_json = tmp_path / "summary.json"
-        out_bench = tmp_path / "obs.json"
-        rc = trace_report.main([str(tmp_path), "--json", str(out_json),
-                                "--bench", str(out_bench)])
+        rc = trace_report.main([str(tmp_path), "--json", str(out_json)])
         assert rc == 0
-        assert "solver/superstep" in capsys.readouterr().out
-        assert json.loads(out_json.read_text())["n_spans"] == 2
-        assert json.loads(out_bench.read_text())["figure"] == "obs"
+        out = capsys.readouterr().out
+        assert "solver/superstep" in out and "self_ms" in out
+        summary = json.loads(out_json.read_text())
+        assert summary["n_spans"] == 2
+        [row] = summary["spans"]
+        assert row["self_ms"] == row["total_ms"] == pytest.approx(5.0,
+                                                                   rel=0.01)
+
+
+# ----------------------------------------------------- the solver's spans
+
+def _spans(events):
+    """Every balanced span of one thread's export, in the order they
+    close: {name, args, parent, t0, t1} (µs)."""
+    out, stack = [], []
+    for e in events:
+        if e["ph"] == "B":
+            stack.append(e)
+        elif e["ph"] == "E":
+            b = stack.pop()
+            out.append({"name": b["name"], "args": b.get("args"),
+                        "parent": stack[-1]["name"] if stack else None,
+                        "t0": b["ts"], "t1": e["ts"]})
+    return out
+
+
+def _solver(**kw):
+    from repro.core.dglmnet import DGLMNETConfig
+    from repro.core.solver import GLMSolver
+    from repro.data import synthetic
+
+    ds = synthetic.make_dense(n=96, p=32, k_true=4, seed=3)
+    return GLMSolver(ds.train.X, np.asarray(ds.train.y), config=DGLMNETConfig(
+        family="logistic", tile_size=8, coupling="jacobi", max_outer=12,
+        tol=1e-7), **kw)
+
+
+def _traced(fn):
+    """Run ``fn`` with the module tracer on in memory; its spans."""
+    tr = trace.enable(None, jax_annotations=False)
+    try:
+        fn()
+    finally:
+        trace.disable()
+    return _spans(tr.export()["traceEvents"])
+
+
+class TestSolverSpans:
+    PARENT = {"solver/lambda": "solver/path", "solver/screen": "solver/lambda",
+              "solver/kkt": "solver/lambda", "solver/superstep": "solver/run",
+              "solver/sync": "solver/superstep"}
+
+    def test_fit_path_exports_the_span_tree(self):
+        s = _solver()
+        K = 4
+        spans = _traced(lambda: s.fit_path(n_lambdas=K, lam_ratio=0.05))
+        names = [sp["name"] for sp in spans]
+        for name in ("solver/path", "solver/lambda", "solver/screen",
+                     "solver/run", "solver/superstep", "solver/sync",
+                     "solver/kkt"):
+            assert name in names, name
+        assert names.count("solver/path") == 1 and names[-1] == "solver/path"
+        for sp in spans:
+            if sp["name"] in self.PARENT:
+                assert sp["parent"] == self.PARENT[sp["name"]], sp
+        # every KKT round of a λ is one solver/run inside it; the λ_max
+        # null fit (the intercept) is one run of the path outside any λ
+        runs = [sp for sp in spans if sp["name"] == "solver/run"]
+        in_lambda = [sp for sp in runs if sp["parent"] == "solver/lambda"]
+        assert {sp["parent"] for sp in runs} <= {"solver/lambda",
+                                                 "solver/path"}
+        assert len(in_lambda) == s.launch_stats["kkt_rounds"]
+        assert all(sp["args"]["round"] >= 0 for sp in in_lambda)
+        assert [sp["args"]["k"] for sp in spans
+                if sp["name"] == "solver/lambda"] == list(range(K))
+        # the sync nests in its superstep, which closes after it
+        steps = [sp for sp in spans if sp["name"] == "solver/superstep"]
+        syncs = [sp for sp in spans if sp["name"] == "solver/sync"]
+        assert len(steps) == len(syncs) == s.launch_stats["supersteps"]
+        for st, sy in zip(steps, syncs):
+            assert st["t0"] <= sy["t0"] <= sy["t1"] <= st["t1"]
+        assert s.launch_stats["lambdas"] == K
+        assert s.launch_stats["kkt_rounds"] >= K
+
+    @pytest.mark.parametrize("telemetry", [False, True])
+    def test_one_superstep_span_per_superstep_under_a_fault_plan(
+            self, telemetry):
+        from repro.dist import faults
+        from repro.dist.telemetry import SuperstepTelemetry
+
+        from repro.sharding import compat
+
+        # telemetry-driven ALB maps node speeds onto a mesh's columns
+        s = _solver(fault_plan=faults.FaultPlan(num_processes=1,
+                                                tile_cost_s=1e-5),
+                    **({"telemetry": SuperstepTelemetry(1, warmup=1),
+                        "mesh": compat.make_mesh((1, 1), ("data", "model"))}
+                       if telemetry else {}))
+        lams = s.lambda_max() * np.array([0.5, 0.2])
+        n0 = s.launch_stats["supersteps"]
+        spans = _traced(lambda: s.fit_path(lambdas=lams))
+        steps = [sp for sp in spans if sp["name"] == "solver/superstep"]
+        assert len(steps) == s.launch_stats["supersteps"] - n0 > 0
+        assert all(set(sp["args"]) == {"step", "tiles"} for sp in steps)
+        syncs = [sp for sp in spans if sp["name"] == "solver/sync"]
+        assert len(syncs) == len(steps)
+        assert all(sp["parent"] == "solver/superstep" for sp in syncs)
+        assert all(sp["parent"] == "solver/run" for sp in steps)
+        assert s.launch_stats["lambdas"] == 2
+
+    def test_step_us_is_the_superstep_from_dispatch_to_sync(self, tmp_path):
+        s = _solver()
+        path = tmp_path / "conv.jsonl"
+        s.set_convergence_stream(path)
+        spans = _traced(lambda: s.fit(lam1=0.01, max_outer=5, tol=0.0))
+        events = conv.read_events(path)
+        steps = [sp for sp in spans if sp["name"] == "solver/superstep"]
+        syncs = [sp for sp in spans if sp["name"] == "solver/sync"]
+        assert len(events) == len(steps) == len(syncs) == 5
+        for ev, st, sy in zip(events, steps, syncs):
+            assert ev["step_us"] >= sy["t1"] - sy["t0"]
+            assert ev["step_us"] == pytest.approx(st["t1"] - st["t0"],
+                                                  abs=1.0)
+        # a single fit is one solver/run outside any λ
+        [run] = [sp for sp in spans if sp["name"] == "solver/run"]
+        assert run["parent"] is None and run["args"] is None
