@@ -11,6 +11,14 @@ every grid step and accumulated in VMEM (initialized at step 0).  The step
 sizes sit in SMEM (a scalar read per candidate), and candidate k's loss sum
 lands in lane k of the output row through a lane mask — Mosaic indexes
 neither in-register vectors nor VMEM lanes dynamically.
+
+This kernel reduces each candidate's loss to a scalar in every block.  The
+fused superstep's ``superstep_tile.margin_ls_pallas`` does not: its
+1024-row blocks are one (8, 128) vreg, so a reduction per candidate per
+block was latency-bound, and it accumulates element-wise instead.  Here a
+block is 256 × 128 (32 vregs per reduction), the reduction weighs 32 times
+less, and this kernel serves only the unfused path (sparse bricks, sharded
+meshes, non-Jacobi coupling).
 """
 from __future__ import annotations
 

@@ -21,7 +21,13 @@ The two kernels here collapse that chain to TWO launches:
   it accumulates the margin delta xdb = X·Δβ over tiles in a VMEM-resident
   block, and at the last tile evaluates every line-search candidate's loss
   against that block — xdb never round-trips HBM between the margin apply
-  and the candidate sweep.
+  and the candidate sweep.  The candidate losses accumulate element-wise:
+  a VMEM scratch holds one (8, 128) tile per candidate (a sublane-reduced
+  row past ``_LS_ACC_BUDGET``), each row block adds its masked losses into
+  candidate k's tile with no cross-lane work, and
+  the launch's last grid step reduces each tile once into lane k of the
+  (1, K) output.  A per-block reduction per candidate (391 × 294 of them a
+  superstep at 400,000 rows) is latency-bound and cost 4× the X read.
 
 Active-set shaping (tentpole b): the first kernel takes a scalar-prefetch
 remap ``sel = [live-first tile order..., n_live]``; grid steps with
@@ -50,9 +56,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.alpha_search import candidate_losses
 from repro.kernels.cd_tile_solve import solve_chain
-from repro.kernels.glm_stats import _STATS
+from repro.kernels.glm_stats import _LOSS, _STATS
 
 MU, NU, LAM1, LAM2 = 0, 1, 2, 3  # params (4,) SMEM layout, as cd_tile_solve
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -60,6 +65,11 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # block, Gram block and the transposed operand need ~20 MiB, above Mosaic's
 # 16 MiB default (a v5e core has 128 MiB of VMEM)
 _GRAM_VMEM_LIMIT = 48 << 20
+# the line search's candidate-loss accumulator holds a row block's whole
+# (8, 128) tile per candidate up to this size (1.2 MB at the default 294
+# candidates), else one sublane-reduced row per candidate
+_LS_ACC_BUDGET = 64 << 20
+_LS_UNROLL = 16        # candidates per iteration of the accumulation loop
 
 
 def _matmul_inputs(precision, *xs):
@@ -193,11 +203,51 @@ def stats_gram_solve_pallas(sel, Xt3, y2, xb2, mask2, beta_r, penf_r, params,
     return loss2, s2, w2, G_all, g_all[:, 0], dbeta_r[:, 0]
 
 
+def _ls_acc_rows(K, br):
+    """Sublane rows of the candidate-loss accumulator: a row block's whole
+    (br, 128) tile per candidate while K of them fit ``_LS_ACC_BUDGET``,
+    else one sublane-reduced row per candidate."""
+    return br if K * br * 128 * 4 <= _LS_ACC_BUDGET else 1
+
+
+def _accumulate_candidates(alphas_ref, acc_ref, y, xb, xdb, mask, *, family):
+    """acc[k] += mask · l(y, xb + alphas[k]·xdb) for every candidate k:
+    element-wise, no cross-lane reduction.  ``_LS_UNROLL`` candidates per
+    loop iteration, so one candidate's exp/log overlaps the next's."""
+    loss = _LOSS[family]
+    K, rows = alphas_ref.shape[0], acc_ref.shape[1]
+
+    def one(k):
+        lk = mask * loss(y, xb + alphas_ref[k] * xdb)
+        if rows != lk.shape[0]:
+            lk = jnp.sum(lk, axis=0, keepdims=True)
+        acc_ref[k] += lk
+
+    def chunk(c, carry):
+        for u in range(_LS_UNROLL):
+            one(c * _LS_UNROLL + u)
+        return carry
+
+    jax.lax.fori_loop(0, K // _LS_UNROLL, chunk, 0)
+    for k in range(K - K % _LS_UNROLL, K):
+        one(k)
+
+
 def _margin_ls_kernel(alphas_ref, Xt_ref, db_ref, y_ref, xb_ref, mask_ref,
-                      xdb_ref, out_ref, *, family, precision):
+                      xdb_ref, out_ref, acc_ref, *, family, precision):
+    """Grid step (i, t): add tile t's share of row block i's margin delta
+    to xdb; at the block's last tile add every candidate's masked loss
+    element-wise into ``acc_ref`` (K, rows, 128), zeroed at step (0, 0);
+    at the launch's last step reduce ``acc_ref`` into the (1, K) output,
+    which is written only there."""
     i = pl.program_id(0)
     t = pl.program_id(1)
+    nb = pl.num_programs(0)
     nt = pl.num_programs(1)
+
+    @pl.when((i == 0) & (t == 0))
+    def _init_acc():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when(t == 0)
     def _init_xdb():
@@ -214,13 +264,19 @@ def _margin_ls_kernel(alphas_ref, Xt_ref, db_ref, y_ref, xb_ref, mask_ref,
 
     @pl.when(t == nt - 1)
     def _linesearch():
-        @pl.when(i == 0)
-        def _init_out():
-            out_ref[...] = jnp.zeros_like(out_ref)
+        _accumulate_candidates(alphas_ref, acc_ref, y_ref[...], xb_ref[...],
+                               xdb_ref[...], mask_ref[...], family=family)
 
-        out_ref[...] += candidate_losses(
-            alphas_ref, y_ref[...], xb_ref[...], xdb_ref[...],
-            mask_ref[...], family=family)
+    # one cross-lane reduction per candidate for the whole launch: sublanes
+    # on the VPU, then lanes on the MXU, as ones(1, 128) · sumsᵀ, which lays
+    # candidate k's total in lane k of the (1, K) output row
+    @pl.when((i == nb - 1) & (t == nt - 1))
+    def _reduce():
+        sums = jnp.sum(acc_ref[...], axis=1)                     # (K, 128)
+        out_ref[...] = jax.lax.dot_general(
+            jnp.ones((1, sums.shape[1]), jnp.float32), sums,
+            (((1,), (1,)), ((), ())), precision=_HIGHEST,
+            preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("family", "block_n", "precision",
@@ -239,6 +295,10 @@ def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, *, family,
     R, C = y2.shape
     K = alphas.shape[0]
     f32 = jnp.float32
+    rows = _ls_acc_rows(K, br)
+    # scoped VMEM from the shapes: the double-buffered design block, its
+    # transposed or bf16 copy, the accumulator, and room for the rest
+    vmem = 3 * block_n * T * 4 + K * rows * 128 * 4 + (8 << 20)
     vspec = pl.BlockSpec((br, C), lambda i, t: (i, 0))
     out = pl.pallas_call(
         functools.partial(_margin_ls_kernel, family=family,
@@ -253,6 +313,9 @@ def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, *, family,
         out_specs=[vspec, pl.BlockSpec((1, K), lambda i, t: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C), f32),
                    jax.ShapeDtypeStruct((1, K), f32)],
+        scratch_shapes=[pltpu.VMEM((K, rows, C), f32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem, 16 << 20)),
         interpret=interpret,
     )(alphas.astype(f32), Xt3.astype(f32),
       dbeta_r.astype(f32).reshape(nt, 1, T), y2.astype(f32),

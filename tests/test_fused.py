@@ -124,6 +124,93 @@ def test_fused_pallas_kernels_match_oracle(family):
                                rtol=1e-5, atol=1e-3)
 
 
+def _ls_parity_problem(family, n, p, T, seed):
+    """A dense design and a line-search direction from the oracle's tile
+    solves, under sample weights and an offset, with moderate margins."""
+    rng = np.random.default_rng(seed)
+    X = (0.2 * rng.normal(size=(n, p))).astype(np.float32)
+    design, _ = design_lib.dense_design(jnp.asarray(X), T)
+    beta = (0.3 * rng.normal(size=p) * (rng.random(p) < 0.3)).astype(
+        np.float32)
+    m = X @ beta
+    y = {"logistic": rng.choice([-1.0, 1.0], n),
+         "probit": rng.choice([-1.0, 1.0], n),
+         "squared": m + rng.normal(size=n),
+         "poisson": rng.poisson(np.exp(m))}[family].astype(np.float32)
+    w, off, pf = _obs_features(n, p, seed + 1)
+    return design, jnp.asarray(y), jnp.asarray(beta), jnp.asarray(w), \
+        jnp.asarray(off), jnp.asarray(pf)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_margin_ls_matches_oracle_at_full_candidates(family):
+    """``margin_ls_pallas`` (interpret mode, through ``ops.fused_ls``)
+    against ``ref.fused_ls_dense`` at the benchmark's candidate count: the
+    294 step sizes of ``full_candidates``, three 1024-row blocks with a
+    ragged, masked tail, sample weights and an offset.  The losses agree to
+    f32 summation order, and ``select_precomputed`` picks the same α."""
+    from repro.core import linesearch
+    cfg = DGLMNETConfig(family=family)
+    n, p, T = 2 * 1024 + 700, 256, 128
+    design, y, beta, w, off, pf = _ls_parity_problem(family, n, p, T, 13)
+    xb = design.matvec(beta)
+    lam1, lam2 = 0.02, 0.01
+    loss_i, s, wt, dbeta, _, _ = ops.fused_stats_sweep(
+        design, y, xb, beta, family, mu=1.0, nu=1e-6, lam1=lam1, lam2=lam2,
+        weights=w, offset=off, penf=pf, backend="ref")
+    assert np.abs(np.asarray(dbeta)).max() > 0
+    cand = linesearch.full_candidates(cfg.ls_delta, cfg.ls_grid_size,
+                                      cfg.backtrack_b, cfg.max_backtracks)
+    assert cand.shape == (294,)
+    out = {b: ops.fused_ls(design, y, xb, dbeta, cand, family, weights=w,
+                           offset=off, backend=b)
+           for b in ("ref", "pallas")}
+    (xdb_r, ls_r), (xdb_p, ls_p) = out["ref"], out["pallas"]
+    np.testing.assert_allclose(np.asarray(xdb_p), np.asarray(xdb_r),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(ls_p), np.asarray(ls_r), rtol=1e-5)
+    R0 = linesearch.penalty_terms(beta, jnp.zeros_like(beta),
+                                  jnp.zeros((1,)), lam1, lam2, None, pf)[0]
+    grad_dot_dir = -jnp.sum(s * xdb_r)
+    quad_form = jnp.sum(wt * xdb_r * xdb_r) + 1e-6 * jnp.sum(dbeta * dbeta)
+    alphas = [
+        float(linesearch.select_precomputed(
+            losses, cand, beta, dbeta, lam1, lam2,
+            f_current=jnp.sum(loss_i) + R0, grad_dot_dir=grad_dot_dir,
+            quad_form=quad_form, sigma=cfg.sigma, gamma=cfg.gamma,
+            grid_size=cfg.ls_grid_size, max_backtracks=cfg.max_backtracks,
+            penf=pf).alpha)
+        for losses in (ls_r, ls_p)]
+    assert alphas[0] == alphas[1], alphas
+
+
+def test_margin_ls_sublane_reduced_accumulator(monkeypatch):
+    """A candidate set too large for the whole-tile accumulator falls back
+    to one sublane-reduced row per candidate, with the same losses."""
+    from repro.core import linesearch
+    from repro.kernels import ref, superstep_tile
+    assert superstep_tile._ls_acc_rows(294, 8) == 8
+    assert superstep_tile._ls_acc_rows(1 << 20, 8) == 1
+    n, p, T = 1024 + 300, 256, 128
+    design, y, beta, w, off, _ = _ls_parity_problem("logistic", n, p, T, 17)
+    dbeta = jnp.asarray(np.random.default_rng(18).normal(size=p) * 0.1,
+                        jnp.float32)
+    xb = design.matvec(beta) + off
+    cand = linesearch.full_candidates(1e-3, 13, 0.5, 20)
+    Xt3 = design.tiles3()
+    (y2, xb2, w2), pad = ops._pack_rows(Xt3, y, xb, w)
+    monkeypatch.setattr(superstep_tile, "_LS_ACC_BUDGET", 0)
+    # unjitted, so the patched budget is read when the kernel is traced
+    _, losses = superstep_tile.margin_ls_pallas.__wrapped__(
+        Xt3, dbeta.reshape(-1, T), y2, xb2, w2 * pad, cand,
+        family="logistic", interpret=True)
+    pad_to = lambda v: jnp.pad(v, (0, Xt3.shape[1] - n))
+    _, want = ref.fused_ls_dense(Xt3, pad_to(y), pad_to(xb), dbeta,
+                                 pad_to(w), cand, "logistic")
+    np.testing.assert_allclose(np.asarray(losses), np.asarray(want),
+                               rtol=1e-5)
+
+
 def test_screened_tiles_cost_zero_sweep_launches():
     """Host-side launch bookkeeping: along a screened λ-path, fully
     screened-out tiles are skipped by the active-set-shaped launch and the

@@ -32,6 +32,7 @@ SPARSE_R = 32768 // 128
 DENSE_R = 3328                   # 400,000 rows in 256-row (R, 128) blocks
 DENSE_NPAD = 400_384             # 400,000 rows in 1024-row blocks
 N_CAND = 294                     # (1 + 13) · (1 + 20) line-search candidates
+N_CAND_WIDE = 574                # (1 + 13) · (1 + 40): max_backtracks = 40
 
 
 @pytest.fixture(scope="module")
@@ -131,13 +132,23 @@ def test_stats_gram_solve(one_chip, T, n_pad, p, precision):
              ((4,), F32))
 
 
-@pytest.mark.parametrize("T,n_pad,p,precision",
-                         [(256, DENSE_NPAD, 2048, "fp32"),
-                          (256, DENSE_NPAD, 2048, "bf16"),
-                          (512, 32768, 65536, "fp32")])
-def test_margin_ls(one_chip, T, n_pad, p, precision):
+@pytest.mark.parametrize(
+    "T,n_pad,p,precision,family,K",
+    [pytest.param(256, DENSE_NPAD, 2048, "fp32", "logistic", N_CAND,
+                  id="256-400384-2048-fp32"),
+     pytest.param(256, DENSE_NPAD, 2048, "bf16", "logistic", N_CAND,
+                  id="256-400384-2048-bf16"),
+     pytest.param(512, 32768, 65536, "fp32", "logistic", N_CAND,
+                  id="512-32768-65536-fp32"),
+     # the heaviest loss body, and the accumulator's VMEM sized from a
+     # larger candidate set at the wider tile
+     pytest.param(256, DENSE_NPAD, 2048, "fp32", "probit", N_CAND,
+                  id="256-400384-2048-fp32-probit"),
+     pytest.param(512, DENSE_NPAD, 4096, "fp32", "logistic", N_CAND_WIDE,
+                  id="512-400384-4096-fp32-K574")])
+def test_margin_ls(one_chip, T, n_pad, p, precision, family, K):
     nt, R = _fused_shapes(T, n_pad, p)
-    fn = lambda *a: margin_ls_pallas(*a, family="logistic",
+    fn = lambda *a: margin_ls_pallas(*a, family=family,
                                      precision=precision, interpret=False)
     _compile(one_chip, fn, ((nt, n_pad, T), F32), ((nt, T), F32),
-             *[((R, 128), F32)] * 3, ((N_CAND,), F32))
+             *[((R, 128), F32)] * 3, ((K,), F32))
