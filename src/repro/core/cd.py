@@ -46,6 +46,19 @@ def _psum(x, axis: Optional[str]):
     return jax.lax.psum(x, axis) if axis is not None else x
 
 
+def coordinate_prox(g, h, beta, pf, *, mu, nu, lam1, lam2):
+    """Δβ of one exact coordinate step for every coordinate at once: the
+    tile solve's chain (``ref.cd_tile_solve``) for a tile of one feature,
+    from Δβ = 0.  ``g`` = Xᵀs and ``h`` = diag XᵀWX at the iterate; the
+    blocks of one feature are coupled by Jacobi, as tiles are."""
+    den = mu * h + nu + lam2 * pf
+    num = g + mu * h * beta + nu * beta
+    u = jnp.sign(num) * jnp.maximum(jnp.abs(num) - lam1 * pf, 0.0) \
+        / jnp.maximum(den, 1e-30)
+    # dead coordinate (all-zero column, nu == lam2 == 0): keep at 0
+    return jnp.where(den > 0, u, beta) - beta
+
+
 def alb_live_mask(n_tiles: int, start_tile, num_tiles):
     """(n_tiles,) bool: tiles [start, start+budget) in cyclic order — the
     ALB budget window of one jacobi sweep (Section 7).  Shared by the

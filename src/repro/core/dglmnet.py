@@ -76,6 +76,12 @@ class DGLMNETConfig:
     # outer loop:
     max_outer: int = 100
     tol: float = 1e-8
+    # layout of a ``SparseRows`` input (data/design.py HeadTailDesign): the
+    # number of most frequent features held dense, a multiple of tile_size
+    head_features: Optional[int] = None
+    # model: an unpenalized intercept column (GLMSolver's ``fit_intercept``
+    # argument, where given, overrides this)
+    fit_intercept: bool = False
 
 
 class FitState(NamedTuple):
@@ -145,21 +151,47 @@ def make_superstep(config: DGLMNETConfig, *, axis_data=None, axis_model=None,
         lam1, lam2 = lams[0], lams[1]
         T = config.tile_size
         nt = n_tiles_local
+        # a head/tail design runs its dense head through the two fused
+        # launches and its sparse tail through one XLA step between them:
+        # one exact coordinate step per tail feature, Jacobi-coupled, over
+        # the working set of tail columns the screening lets move
+        tail = design if isinstance(design, design_lib.HeadTailDesign) \
+            else None
+        if tail is None:
+            swept, head_of = design, (lambda v: v)
+        else:
+            swept, head_of = tail.head, (lambda v: tail.split(v)[0])
+        nt_swept = head_of(beta).shape[0] // T
 
         # tile occupancy = ALB budget window ∧ any-active-coordinate: dead
         # tiles cost no Gram/solve work (active-set-shaped launch)
-        alb_live = cd_lib.alb_live_mask(nt, cursor[0], budget[0])
-        tile_act = jnp.any(active.reshape(nt, T) > 0, axis=1)
+        alb_live = cd_lib.alb_live_mask(nt_swept, cursor[0], budget[0])
+        tile_act = jnp.any(head_of(active).reshape(nt_swept, T) > 0, axis=1)
         tile_live = alb_live & tile_act
 
         # (1+2) fused launch: stats + every live tile's Gram/gradient +
         # the Jacobi tile solves, one pass over the rows
         loss_i, s, w, dbeta, _, _ = ops.fused_stats_sweep(
-            design, y, xb, beta, fam, mu=mu, nu=config.nu,
+            swept, y, xb, head_of(beta), fam, mu=mu, nu=config.nu,
             lam1=lam1, lam2=lam2, weights=weights, offset=offset,
-            penf=penf, tile_live=tile_live,
+            penf=head_of(penf), tile_live=tile_live,
             precision=config.precision, backend=backend)
-        dbeta = jnp.where(active > 0, dbeta, 0.0)
+        dbeta = jnp.where(head_of(active) > 0, dbeta, 0.0)
+        xdb_tail = None
+        if tail is not None:
+            # the tail between the launches: each feature's gradient and
+            # diagonal Hessian, one exact coordinate step, its margin delta
+            _, beta_t = tail.split(beta)
+            with jax.named_scope("head_tail/tail_stats"):
+                g_t, h_t = tail.tail_stats_ws(s, w)
+                dbeta_t = jnp.where(tail.split(active)[1] > 0,
+                                    cd_lib.coordinate_prox(
+                                        g_t, h_t, beta_t, tail.split(penf)[1],
+                                        mu=mu, nu=config.nu, lam1=lam1,
+                                        lam2=lam2), 0.0)
+            with jax.named_scope("head_tail/tail_margin"):
+                xdb_tail = tail.tail_matvec_ws(dbeta_t)
+            dbeta = jnp.concatenate([dbeta, dbeta_t])
         L = jnp.sum(loss_i)
         R0 = linesearch.penalty_terms(beta, jnp.zeros_like(beta),
                                       jnp.zeros((1,)), lam1, lam2, None,
@@ -168,23 +200,43 @@ def make_superstep(config: DGLMNETConfig, *, axis_data=None, axis_model=None,
 
         # (3+4) fused launch: margin delta + candidate losses; Algorithm-3
         # selection happens on the accumulated scalars (same decisions as
-        # linesearch.search — see select_precomputed)
-        if one_pass_ls:
+        # linesearch.search — see select_precomputed).  A head/tail design
+        # scores each candidate by its change of the objective, summed row
+        # by row and coordinate by coordinate, and reports the chosen
+        # change ("df") for the outer loop's stopping test: a hashed
+        # design's supersteps move a large objective by less than the
+        # rounding of its float32 sum, which the differences of whole sums
+        # would turn into accepted increases and early stops
+        if one_pass_ls or tail is not None:
             cand = linesearch.full_candidates(
                 config.ls_delta, config.ls_grid_size, config.backtrack_b,
                 config.max_backtracks)
             xdb, losses = ops.fused_ls(
-                design, y, xb, dbeta, cand, fam, weights=weights,
-                offset=offset, precision=config.precision, backend=backend)
+                swept, y, xb, head_of(dbeta), cand, fam, weights=weights,
+                offset=offset, precision=config.precision, backend=backend,
+                xdb_base=xdb_tail, relative=tail is not None)
             grad_dot_dir = -jnp.sum(s * xdb)
             quad_form = (mu * jnp.sum(w * xdb * xdb)
                          + config.nu * jnp.sum(dbeta * dbeta))
-            ls = linesearch.select_precomputed(
-                losses, cand, beta, dbeta, lam1, lam2, f_current=f_cur,
-                grad_dot_dir=grad_dot_dir, quad_form=quad_form,
-                sigma=config.sigma, gamma=config.gamma,
-                grid_size=config.ls_grid_size,
-                max_backtracks=config.max_backtracks, penf=penf)
+            select = dict(grad_dot_dir=grad_dot_dir, quad_form=quad_form,
+                          sigma=config.sigma, gamma=config.gamma,
+                          grid_size=config.ls_grid_size,
+                          max_backtracks=config.max_backtracks)
+            if tail is None:
+                ls = linesearch.select_precomputed(
+                    losses, cand, beta, dbeta, lam1, lam2, f_current=f_cur,
+                    penf=penf, **select)
+            else:
+                # the penalty's changes over the head, and over the tail
+                # columns the working set lets move
+                pen = lambda b, d, pf: linesearch.penalty_changes(
+                    b, d, cand, lam1, lam2, pf)
+                dpens = pen(head_of(beta), head_of(dbeta), head_of(penf)) \
+                    + tail.over_working_set(pen, beta_t, dbeta_t,
+                                            tail.split(penf)[1])
+                ls = linesearch.select_changes(losses, dpens, cand, **select)
+                df = ls.f_new
+                ls = ls._replace(f_new=f_cur + df)
         else:
             xdb = design.matvec(dbeta)
             grad_dot_dir = -jnp.sum(s * xdb)
@@ -217,6 +269,8 @@ def make_superstep(config: DGLMNETConfig, *, axis_data=None, axis_model=None,
             "accepted_unit": ls.accepted_unit.astype(jnp.int32),
             "D": ls.D,
         }
+        if tail is not None:
+            metrics["df"] = df
         return FitState(beta_new, xb_new, mu_new, cursor_new, step + 1), \
             metrics
 

@@ -99,6 +99,37 @@ def select_precomputed(losses, cand, beta, dbeta, lam1, lam2, *, f_current,
     return armijo_select(f_cand[0], f_bt, bt, f_current, sigma, D)
 
 
+def select_changes(dlosses, dpens, cand, *, grad_dot_dir, quad_form, sigma,
+                   gamma, grid_size, max_backtracks) -> LineSearchResult:
+    """``select_precomputed`` from each candidate's CHANGE of the loss
+    (``dlosses``, summed row by row) and of the penalty (``dpens``,
+    ``penalty_changes``): the same decisions in exact arithmetic, but
+    rounded at the size of the changes, so they still hold where a
+    superstep moves the objective by less than the rounding of its float32
+    sum.  The result's ``f_new`` is the chosen step's change of the
+    objective."""
+    K0 = 1 + grid_size
+    B = max_backtracks
+    df = dlosses + dpens
+    D = grad_dot_dir + gamma * quad_form + dpens[0]
+    i0 = jnp.argmin(df[:K0])
+    bt = jax.lax.dynamic_slice(cand, (K0 + i0 * B,), (B,))
+    df_bt = jax.lax.dynamic_slice(df, (K0 + i0 * B,), (B,))
+    return armijo_select(df[0], df_bt, bt, 0.0, sigma, D)
+
+
+def penalty_changes(beta, dbeta, alphas, lam1, lam2, penf=None):
+    """R(β + α·Δβ) − R(β) for every α: (K,), summed coordinate by
+    coordinate (single device)."""
+    pf = jnp.ones_like(beta) if penf is None else penf
+    l1 = jnp.sum(pf[None, :] * (jnp.abs(beta[None, :] + alphas[:, None]
+                                        * dbeta[None, :])
+                                - jnp.abs(beta)[None, :]), axis=-1)
+    bd = jnp.sum(pf * beta * dbeta)
+    d2 = jnp.sum(pf * dbeta * dbeta)
+    return lam1 * l1 + 0.5 * lam2 * (2.0 * alphas * bd + alphas * alphas * d2)
+
+
 def penalty_terms(beta, dbeta, alphas, lam1, lam2, axis_model, penf=None):
     """R(β + α·Δβ) for every α: (K,). beta/dbeta are the LOCAL shards.
 

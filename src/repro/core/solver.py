@@ -56,6 +56,7 @@ the penalized coordinates.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import os
@@ -71,7 +72,8 @@ from repro import compile_cache
 from repro.core import dglmnet, glm
 from repro.core.dglmnet import DGLMNETConfig, FitResult, FitState
 from repro.data import design as design_lib
-from repro.data.design import (BlockSparseDesign, DesignMatrix, SparseCOO,
+from repro.data.design import (BlockSparseDesign, DesignMatrix,
+                               HeadTailDesign, SparseCOO, SparseRows,
                                StreamingDesign)
 from repro.dist import bootstrap as dist_boot
 from repro.kernels import ops
@@ -214,10 +216,18 @@ def _with_intercept_column(X, n: int):
         vals = np.concatenate([np.asarray(X.vals, np.float32),
                                np.ones((n,), np.float32)])
         return SparseCOO(rows, cols, vals, (n, p + 1))
+    if isinstance(X, SparseRows):
+        # one more pair a row, on the device: feature p with value 1
+        n_rows = X.ids.shape[0]
+        ids = jnp.concatenate([jnp.asarray(X.ids, jnp.int32), jnp.full(
+            (n_rows, 1), X.n_features, jnp.int32)], axis=1)
+        vals = jnp.concatenate([jnp.asarray(X.vals, jnp.float32),
+                                jnp.ones((n_rows, 1), jnp.float32)], axis=1)
+        return SparseRows(ids, vals, X.n_features + 1)
     if isinstance(X, DesignMatrix):
         raise ValueError(
-            "fit_intercept=True needs a raw input (dense array or "
-            "SparseCOO): the intercept column must be appended before the "
+            "fit_intercept=True needs a raw input (dense array, SparseCOO "
+            "or SparseRows): the intercept column must be appended before the "
             "design is packed; pre-built designs should carry their own "
             "constant column")
     X = np.asarray(X, np.float32)
@@ -244,6 +254,7 @@ class GLMSolver:
         their own offset for new rows.
       * ``fit_intercept``: append an unpenalized all-ones column; the fitted
         intercept is split off into ``intercept_`` and never penalized.
+        None (the default) takes ``config.fit_intercept``.
       * ``standardize``: fit on weighted-variance-1 columns (dense layouts
         with an intercept are also mean-centered; brick layouts are
         scale-only, glmnet-style for sparse inputs) and return β on the
@@ -274,7 +285,8 @@ class GLMSolver:
                  row_block: int = 256, reorder: bool = True,
                  design_info=None,
                  sample_weight=None, offset=None,
-                 standardize: bool = False, fit_intercept: bool = False,
+                 standardize: bool = False,
+                 fit_intercept: Optional[bool] = None,
                  penalty_factor=None,
                  telemetry=None, fault_plan=None):
         compile_cache.init()
@@ -326,15 +338,18 @@ class GLMSolver:
                 f"but the job has {self.dist_info['num_processes']}")
         self.beta_: Optional[np.ndarray] = None
         self.intercept_: float = 0.0
-        self.fit_intercept = bool(fit_intercept)
+        self.fit_intercept = bool(config.fit_intercept if fit_intercept
+                                  is None else fit_intercept)
         self.standardize = bool(standardize)
         self._state: Optional[FitState] = None
         self._lmax: Optional[float] = None
+        self._grad0: Optional[np.ndarray] = None   # Xᵀs at β = 0
         self._matvec_fn = None
         self._grad_fn = None
         self._dev_fn = None
         self._streaming = False
         self._serve_cache = None        # (key, ScoringEngine) for predict
+        self._ws_cache = None           # (key, design, entries) _round_design
         # host-side sweep launch bookkeeping (active-set-shaped launches,
         # DESIGN.md §8): tiles the CD sweep actually processed vs skipped
         # because every coordinate was screened out.  In-memory fits only.
@@ -342,7 +357,7 @@ class GLMSolver:
         # calls (KKT rounds) it made for them.
         self.launch_stats = {"supersteps": 0, "sweep_tile_launches": 0,
                              "sweep_tiles_skipped": 0, "lambdas": 0,
-                             "kkt_rounds": 0}
+                             "kkt_rounds": 0, "tail_entries": 0}
         # convergence event stream (repro.obs, DESIGN.md §12): auto-opened
         # next to the trace shards when tracing targets a directory, or
         # attached explicitly via set_convergence_stream().
@@ -398,8 +413,17 @@ class GLMSolver:
             X = _with_intercept_column(X, n)
 
         if mesh is None:
-            design, info = design_lib.as_design(
-                X, T, row_block=row_block, reorder=reorder, info=design_info)
+            if isinstance(X, SparseRows) and not (
+                    config.fuse_superstep and config.coupling == "jacobi"):
+                raise ValueError(
+                    "SparseRows train through the fused Jacobi superstep "
+                    "(fuse_superstep=True, coupling='jacobi'): the sparse "
+                    "tail has no per-tile Gram")
+            with obs_trace.span("solver/pack_head_tail") \
+                    if isinstance(X, SparseRows) else contextlib.nullcontext():
+                design, info = design_lib.as_design(
+                    X, T, row_block=row_block, reorder=reorder,
+                    info=design_info, head_features=config.head_features)
             self._info = info
             self._streaming = isinstance(design, StreamingDesign)
             if self._streaming and design.tile_size != T:
@@ -432,6 +456,12 @@ class GLMSolver:
                     "row_block": design.row_block, "reorder": bool(reorder)}
                 layout_key = ("bricks", T, design.row_block, design.n_rows,
                               design.n_tiles, design.max_bricks_per_tile)
+            elif isinstance(design, HeadTailDesign):
+                self._design_layout = {
+                    "kind": "head_tail", "tile": T,
+                    "head": design.head_width}
+                layout_key = ("head_tail", T, design.head_width, n_rows,
+                              p_pad, design.tail_width)
             else:
                 self._design_layout = None
                 layout_key = ("dense",)
@@ -443,6 +473,10 @@ class GLMSolver:
                     "StreamingDesign is a single-process out-of-core layout; "
                     "it cannot be mesh-sharded (mesh=None). Shard rows by "
                     "giving each process its own chunk range instead")
+            if isinstance(X, SparseRows):
+                raise ValueError(
+                    "SparseRows train on one device (mesh=None): the "
+                    "head/tail layout is not sharded over a mesh yet")
             D = mesh.shape[axis_data] if axis_data else 1
             M = mesh.shape[axis_model]
             self._D, self._M = D, M
@@ -530,6 +564,10 @@ class GLMSolver:
                                          cursor=self._feat_spec, step=P())
 
         # --- observation model: weights, offsets, penalty factors ----------
+        # packed columns from here on are screened by their own KKT
+        # condition, not the strong rule (a head/tail design's tail)
+        self._tail_start = self._Xs.head_width \
+            if isinstance(self._Xs, HeadTailDesign) else self._p_tot
         self._p_model = self._info.shape[1]       # columns incl. intercept
         self._p_user = self._p_model - (1 if self.fit_intercept else 0)
         self._wobs_host = np.pad(sw, (0, self._n_tot - n))   # padding → 0
@@ -913,7 +951,7 @@ class GLMSolver:
         return int(max(self._budgets_host[m]
                        for m in self.dist_info["local_columns"]))
 
-    def _dispatch_superstep(self, weights_dev, lams, active_dev, state):
+    def _dispatch_superstep(self, X, weights_dev, lams, active_dev, state):
         """One superstep, from its dispatch to its metrics on the host,
         with the distributed hooks around it (DESIGN.md §9): per-superstep
         budgets, fault-plan work injection, and telemetry recording.
@@ -924,7 +962,7 @@ class GLMSolver:
         budgets = self._budgets()
         if self._telemetry is None and self._faults is None:
             with obs_trace.span("solver/superstep") as sp:
-                state, m = self._superstep(self._Xs, self._ys, weights_dev,
+                state, m = self._superstep(X, self._ys, weights_dev,
                                            self._offsets, budgets, lams,
                                            active_dev, self._penf, state)
                 mh = self._fetch_metrics(m)
@@ -952,7 +990,7 @@ class GLMSolver:
         t0 = time.perf_counter()
         with obs_trace.span("solver/superstep",
                             args={"step": step_no, "tiles": tiles}):
-            state, m = self._superstep(self._Xs, self._ys, weights_dev,
+            state, m = self._superstep(X, self._ys, weights_dev,
                                        self._offsets, budgets, lams,
                                        active_dev, self._penf, state)
             if self._telemetry is not None:
@@ -1060,6 +1098,25 @@ class GLMSolver:
             step_us=self._last_step_us if step_us is None else step_us,
             phase_us=self._last_phase_us if phase_us is None else phase_us)
 
+    def _round_design(self, active):
+        """(design, tail entries read per superstep) for a run of
+        supersteps under the host mask ``active`` (None = every column):
+        a head/tail design carries the working set of its active tail
+        columns (reused while that set is unchanged), or reads its whole
+        tail where the set does not fit; any other design is the placed
+        one."""
+        X = self._Xs
+        if not isinstance(X, HeadTailDesign):
+            return X, 0
+        cols = np.arange(X.tail_cols) if active is None else \
+            np.flatnonzero(np.asarray(active)[X.head_width:] > 0)
+        key = cols.tobytes()
+        if self._ws_cache is None or self._ws_cache[0] != key:
+            X, count = X.with_working_set(self._info.tail_counts, cols)
+            read = X.tail_ids.size if count > X.ws_capacity else count
+            self._ws_cache = (key, X, 2 * int(read))
+        return self._ws_cache[1:]
+
     def _run(self, state: FitState, lam1: float, lam2: float, *,
              kkt_round: Optional[int] = None, **kwargs):
         """Drive supersteps at fixed (λ1, λ2) until the objective plateaus.
@@ -1094,15 +1151,20 @@ class GLMSolver:
 
         # sweep-launch bookkeeping: the active mask is host-known, so the
         # tiles the shaped sweep will skip are too (the compiled superstep
-        # itself is branch-predicated — it never retraces with the mask)
-        total_tiles = self._p_tot // cfg.tile_size
+        # itself is branch-predicated — it never retraces with the mask).
+        # A head/tail design launches tiles over its head; its tail reads
+        # the working set of the active tail columns every superstep
+        X, tail_entries = self._round_design(active)
+        swept_cols = X.head_width if isinstance(X, HeadTailDesign) \
+            else self._p_tot
+        total_tiles = swept_cols // cfg.tile_size
         if active is None:
             live_tiles = total_tiles
             live_active = self._p_tot
         else:
-            act = np.asarray(active, np.float32).reshape(total_tiles,
-                                                         cfg.tile_size)
-            live_tiles = int((act.max(axis=1) > 0).sum())
+            act = np.asarray(active, np.float32)
+            live_tiles = int((act[:swept_cols].reshape(
+                total_tiles, cfg.tile_size).max(axis=1) > 0).sum())
             live_active = int((act > 0).sum())
         shaped = active is not None and self.axis_data is None and (
             cfg.coupling == "gauss-seidel"
@@ -1133,9 +1195,10 @@ class GLMSolver:
             f_prev = md.get("f_prev", np.inf)
             start_it = int(md["next_it"])
         for it in range(start_it, max_outer + 1):
-            state, mh = self._dispatch_superstep(weights_dev, lams,
+            state, mh = self._dispatch_superstep(X, weights_dev, lams,
                                                  active_dev, state)
             self.launch_stats["supersteps"] += 1
+            self.launch_stats["tail_entries"] += tail_entries
             self.launch_stats["sweep_tile_launches"] += \
                 live_tiles if shaped else total_tiles
             if shaped:
@@ -1160,7 +1223,17 @@ class GLMSolver:
                                   metadata={"next_it": it + 1, "f_prev": f,
                                             "design_layout":
                                                 self._design_layout})
-            if np.isfinite(f_prev) and \
+            if "df" in mh:
+                # the superstep measured its own change of the objective and
+                # the change its full step predicts (D), both summed at the
+                # change's size: converged once each is at most tol of the
+                # objective (a line search that found no step moves
+                # nothing, however far from the optimum)
+                if max(abs(float(mh["df"])), abs(float(mh["D"]))) <= \
+                        tol * max(1.0, abs(f)):
+                    converged = True
+                    break
+            elif np.isfinite(f_prev) and \
                     abs(f_prev - f) <= tol * max(1.0, abs(f)):
                 converged = True
                 break
@@ -1408,7 +1481,7 @@ class GLMSolver:
                 raise ValueError(f"offset must be ({n},); got {off.shape}")
             self._offsets = self._place_row(np.pad(off, (0, pad)))
         self._state = None
-        self._lmax = None
+        self._lmax = self._grad0 = None
         return self
 
     def fit(self, lam1: Optional[float] = None, lam2: Optional[float] = None,
@@ -1458,8 +1531,11 @@ class GLMSolver:
                 state, _, _, _ = self._run(
                     state, 0.0, 0.0, active=(~pen).astype(np.float32),
                     max_outer=50)
-            g = np.abs(self._grad_state(state))
-            self._lmax = float((g[pen] / self._penf_host[pen]).max())
+            g = self._grad_state(state)
+            if not (~pen).any():
+                # the gradient at β = 0, where every path starts
+                self._grad0 = g
+            self._lmax = float((np.abs(g)[pen] / self._penf_host[pen]).max())
         return self._lmax
 
     def _make_grid(self, lambdas, n_lambdas, lam_ratio):
@@ -1594,10 +1670,24 @@ class GLMSolver:
                         # unpenalized coordinate; the previous λ's final
                         # KKT gradient IS the gradient at this warm
                         # iterate, so reuse it
-                        g = self._grad_state(state, weights) \
-                            if g_warm is None else g_warm
-                        thresh = 2.0 * lam1 - (lam_prev if lam_prev is not None
-                                               else lam1)
+                        if g_warm is None and k == 0 and weights is None:
+                            # every path starts at β = 0: one gradient
+                            # there serves the session
+                            if self._grad0 is None:
+                                self._grad0 = self._grad_state(state)
+                            g = self._grad0
+                        elif g_warm is None:
+                            g = self._grad_state(state, weights)
+                        else:
+                            g = g_warm
+                        thresh = np.full_like(pf, 2.0 * lam1 - (
+                            lam_prev if lam_prev is not None else lam1))
+                        # a head/tail design's tail columns enter when they
+                        # violate the KKT condition at the warm start: on a
+                        # coarse grid (λ_{k-1} > 2 λ_k) the strong rule
+                        # keeps every column, and the tail's superstep
+                        # costs what its working set holds
+                        thresh[self._tail_start:] = lam1
                         active = (np.abs(g) >= pf * thresh - 1e-12) | \
                             (self._host(state.beta) != 0.0) | unpen
                     it_k = 0
@@ -1826,7 +1916,8 @@ class GLMSolver:
         logistic/probit, means for squared/poisson).  ``SparseCOO`` inputs
         route through the serving engine's fused sparse scoring (gather +
         dot + link over the compacted active set) rather than a host-side
-        matvec.
+        matvec; ``SparseRows`` are scored by a gather-sum over their
+        pairs.
         """
         beta = self.beta_ if beta is None else np.asarray(beta, np.float32)
         if beta is None:
@@ -1839,7 +1930,10 @@ class GLMSolver:
         if isinstance(X_new, SparseCOO):
             eng = self._serve_engine(beta, intercept)
             return eng.score_coo(X_new, kind=kind, offset=offset)[:, 0]
-        m = np.asarray(X_new, np.float32) @ beta + intercept
+        if isinstance(X_new, SparseRows):
+            m = np.asarray(X_new.matvec(beta)) + intercept
+        else:
+            m = np.asarray(X_new, np.float32) @ beta + intercept
         if offset is not None:
             m = m + np.asarray(offset, np.float32)
         if kind == "link":

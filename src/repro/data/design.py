@@ -27,7 +27,12 @@ pack a ``SparseCOO`` into bricks **without ever materializing the dense
 (maximizing brick occupancy, DESIGN.md §2), then whole tiles are dealt
 round-robin across feature shards so per-shard nnz stays balanced.
 
-A third layout, ``StreamingDesign`` (DESIGN.md §6), keeps the rows out of
+``HeadTailDesign`` (DESIGN.md §2) packs hashed multi-field rows
+(``SparseRows``) on the device: its most frequent features as a dense head
+the fused kernels take, the rest as a sparse tail whose bytes scale with
+its nonzeros.
+
+A fourth layout, ``StreamingDesign`` (DESIGN.md §6), keeps the rows out of
 device memory entirely: the matrix is a host array or a chunk-producing
 callable (a pure function of the chunk index, à la ``data/pipeline.py``),
 and every operator method is an accumulation loop over fixed-size row
@@ -47,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.data.sparse import SparseCOO
+from repro.data.sparse import SparseCOO, SparseRows
 from repro.kernels import ops
 
 
@@ -431,6 +436,385 @@ class BlockSparseDesign(DesignMatrix):
 
 
 # ---------------------------------------------------------------------------
+# dense head + sparse tail (hashed one-hot rows)
+# ---------------------------------------------------------------------------
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class HeadTailDesign(DesignMatrix):
+    """A sparse design split by feature frequency (DESIGN.md §2): the
+    ``head_width`` most frequent features as a dense ``DenseDesign`` (both
+    its copies: tile-major for the fused superstep, row-major for the
+    gradient), the rest as a sparse tail whose bytes scale with its
+    nonzeros, where a brick layout's scale with the bricks they touch
+    (hashed one-hot rows touch one brick per feature tile a row reaches).
+
+    Packed columns: head columns ``[0, H)``, then tail columns
+    ``[H, H + tail_cols)``; a tail column below is local to the tail (the
+    packed column minus H).  Leaves:
+
+      head       DenseDesign over (n_rows, H)
+      tail_ids   (n_rows, K_tail) i32 — the tail by rows (ELL): each row's
+      tail_vals  (n_rows, K_tail) f32   tail entries, padded with value 0
+                 at column ``tail_cols`` (out of range, so a scatter drops
+                 it); the whole-tail products (gradient, margins) read it
+      ws_rows, ws_cols, ws_vals  (ws_capacity,) — the working set: every
+                 entry of the tail columns a superstep may move, padded
+                 with value 0 and out-of-range row and column
+      ws_colset  (ws_capacity / 32,) i32 — the working set's columns,
+                 padded with column ``tail_cols``
+      ws_count   () i32 — its entries; above ``ws_capacity`` (or with more
+                 columns than ``ws_colset`` holds) the superstep reads the
+                 whole tail instead
+
+    XLA gathers and scatters one element at a time on a TPU (7–9 ns each
+    on a v5e), so the superstep's tail reads the working set, in chunks of
+    ``ws_chunk`` entries, and the whole tail only where the working set
+    does not fit.
+    """
+
+    head: DenseDesign
+    tail_ids: jnp.ndarray
+    tail_vals: jnp.ndarray
+    ws_rows: jnp.ndarray
+    ws_cols: jnp.ndarray
+    ws_vals: jnp.ndarray
+    ws_colset: jnp.ndarray
+    ws_count: jnp.ndarray
+    tile_size: int
+    tail_cols: int
+    ws_chunk: int
+
+    def tree_flatten(self):
+        return (self.head, self.tail_ids, self.tail_vals, self.ws_rows,
+                self.ws_cols, self.ws_vals, self.ws_colset, self.ws_count), \
+            (self.tile_size, self.tail_cols, self.ws_chunk)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        return cls(*leaves, *aux)
+
+    @property
+    def head_width(self) -> int:
+        return self.head.data.shape[1]
+
+    @property
+    def shape(self):
+        return (self.head.data.shape[0], self.head_width + self.tail_cols)
+
+    @property
+    def n_tiles(self) -> int:
+        return self.shape[1] // self.tile_size
+
+    @property
+    def tail_width(self) -> int:
+        return self.tail_ids.shape[1]
+
+    @property
+    def ws_capacity(self) -> int:
+        return self.ws_rows.shape[0]
+
+    def split(self, v):
+        """(head part, tail part) of a packed-column vector."""
+        return v[:self.head_width], v[self.head_width:]
+
+    # -- the whole tail -------------------------------------------------------
+
+    def tail_matvec(self, v_t):
+        """X_tail @ v_t: a gather-sum over each row's tail entries."""
+        return jnp.sum(self.tail_vals * jnp.asarray(v_t).at[
+            self.tail_ids].get(mode="fill", fill_value=0.0), axis=1)
+
+    def _tail_sum(self, per_entry):
+        """Column sums over the tail of (n_rows, K_tail) per-entry values
+        (one scatter; a multi-column one runs far slower on a TPU)."""
+        return jnp.zeros((self.tail_cols,), jnp.float32).at[
+            self.tail_ids.reshape(-1)].add(per_entry.reshape(-1), mode="drop")
+
+    def tail_stats(self, s, w):
+        """(X_tailᵀ s, diag X_tailᵀ W X_tail): each tail feature's gradient
+        and diagonal Hessian."""
+        v = self.tail_vals
+        return self._tail_sum(v * s[:, None]), \
+            self._tail_sum(v * v * w[:, None])
+
+    # -- the working set ------------------------------------------------------
+
+    def with_working_set(self, tail_counts, cols):
+        """The same design with the working set of tail columns ``cols``
+        (host, sorted), whose entries ``tail_counts`` (host, per tail
+        column) counts.  A set beyond the capacities makes the superstep
+        read the whole tail.  Returns (design, entries in the set)."""
+        count = int(tail_counts[cols].sum())
+        cap, col_cap = self.ws_capacity, self.ws_colset.shape[0]
+        colset = np.full((col_cap,), self.tail_cols, np.int32)
+        if count > cap or cols.shape[0] > col_cap:
+            count = cap + 1
+        else:
+            colset[:cols.shape[0]] = cols
+        ws = (self.ws_rows, self.ws_cols, self.ws_vals)
+        if 0 < count <= cap:
+            member = np.zeros((self.tail_cols,), bool)
+            member[cols] = True
+            ws = _gather_working_set(self.tail_ids, self.tail_vals,
+                                     jnp.asarray(member), cap)
+        return dataclasses.replace(
+            self, ws_rows=ws[0], ws_cols=ws[1], ws_vals=ws[2],
+            ws_colset=jnp.asarray(colset),
+            ws_count=jnp.asarray(count, jnp.int32)), count
+
+    def _ws_chunks(self, body, init):
+        """Fold ``body(rows, cols, vals, carry)`` over the working set's
+        entries, ``ws_chunk`` at a time, as many chunks as it fills."""
+        C = self.ws_chunk
+        n_chunks = (jnp.minimum(self.ws_count, self.ws_capacity) + C - 1) // C
+
+        def step(c, carry):
+            sl = lambda a: jax.lax.dynamic_slice(a, (c * C,), (C,))
+            return body(sl(self.ws_rows), sl(self.ws_cols),
+                        sl(self.ws_vals), carry)
+
+        return jax.lax.fori_loop(0, n_chunks, step, init)
+
+    def tail_stats_ws(self, s, w):
+        """``tail_stats`` over the working set's columns, zero elsewhere;
+        the whole tail's where the working set does not fit."""
+        def body(rows, cols, vals, gh):
+            sv = vals * s.at[rows].get(mode="fill", fill_value=0.0)
+            hv = vals * vals * w.at[rows].get(mode="fill", fill_value=0.0)
+            return (gh[0].at[cols].add(sv, mode="drop"),
+                    gh[1].at[cols].add(hv, mode="drop"))
+
+        zero = jnp.zeros((self.tail_cols,), jnp.float32)
+        if not self.tail_cols:
+            return zero, zero
+        return jax.lax.cond(
+            self.ws_count <= self.ws_capacity,
+            lambda: self._ws_chunks(body, (zero, zero)),
+            lambda: self.tail_stats(s, w))
+
+    def over_working_set(self, fn, *tail_vectors):
+        """``fn(*tail_vectors)`` for a reduction ``fn`` that is zero on the
+        columns off the working set: evaluated on the set's columns alone
+        where the set fits, else on the whole tail."""
+        if not self.tail_cols:
+            return fn(*tail_vectors)
+
+        def at_set():
+            return fn(*(v.at[self.ws_colset].get(mode="fill", fill_value=0.0)
+                        for v in tail_vectors))
+
+        return jax.lax.cond(self.ws_count <= self.ws_capacity, at_set,
+                            lambda: fn(*tail_vectors))
+
+    def tail_matvec_ws(self, v_t):
+        """``tail_matvec`` for a ``v_t`` that is zero off the working set's
+        columns."""
+        def body(rows, cols, vals, out):
+            return out.at[rows].add(
+                vals * v_t.at[cols].get(mode="fill", fill_value=0.0),
+                mode="drop")
+
+        zero = jnp.zeros((self.shape[0],), jnp.float32)
+        if not self.tail_cols:
+            return zero
+        return jax.lax.cond(
+            self.ws_count <= self.ws_capacity,
+            lambda: self._ws_chunks(body, zero),
+            lambda: self.tail_matvec(v_t))
+
+    # -- operators ------------------------------------------------------------
+
+    def matvec(self, v):
+        vh, vt = self.split(v)
+        return self.head.matvec(vh) + self.tail_matvec(vt)
+
+    def rmatvec(self, r):
+        return jnp.concatenate([self.head.rmatvec(r),
+                                self._tail_sum(self.tail_vals * r[:, None])])
+
+    def col_moments(self, weights):
+        h1, h2 = self.head.col_moments(weights)
+        t1, t2 = self.tail_stats(weights, weights)
+        return jnp.concatenate([h1, t1]), jnp.concatenate([h2, t2])
+
+    def to_dense(self):
+        n = self.shape[0]
+        tail = jnp.zeros((n, self.tail_cols), jnp.float32).at[
+            jnp.arange(n)[:, None], self.tail_ids].add(self.tail_vals,
+                                                        mode="drop")
+        return jnp.concatenate([self.head.data, tail], axis=1)
+
+
+def _cumsum(x):
+    """Inclusive running sum of a 1-D array, as a scan over rows of 1024
+    plus each row's offset (a TPU compiles one flat scan of 10⁶ for half a
+    minute)."""
+    n, B = x.shape[0], 1024
+    rows = jnp.cumsum(jnp.pad(x.astype(jnp.int32), (0, (-n) % B)).reshape(
+        -1, B), axis=1)
+    ends = rows[:, -1]
+    return (rows + (jnp.cumsum(ends) - ends)[:, None]).reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _gather_working_set(tail_ids, tail_vals, member, cap: int):
+    """(rows, cols, vals) of the ELL entries in ``member`` columns, packed
+    in row order into ``cap`` slots; padding past them has value 0 and
+    out-of-range row and column, which the superstep's scatters drop."""
+    n, K = tail_ids.shape
+    ids = tail_ids.reshape(-1)
+    keep = member.at[ids].get(mode="fill", fill_value=False)
+    slot = jnp.where(keep, _cumsum(keep) - 1, cap)
+    src = jnp.full((cap,), ids.shape[0], jnp.int32).at[slot].set(
+        jnp.arange(ids.shape[0], dtype=jnp.int32), mode="drop")
+    used = src < ids.shape[0]
+    return (jnp.where(used, src // K, n),
+            jnp.where(used, ids.at[src].get(mode="fill", fill_value=0),
+                      member.shape[0]),
+            jnp.where(used, tail_vals.reshape(-1).at[src].get(
+                mode="fill", fill_value=0.0), 0.0))
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _frequency_columns(ids, vals, p: int, H: int):
+    """Packed column of each feature: the H most frequent features (ties
+    by feature id) ranked in columns [0, H), most frequent first, the rest
+    after them in feature order.  The head is found by bisecting on the
+    H-th largest count, so no sort runs over the features (a TPU sort of
+    10⁶ keys takes half a minute to compile for a TPU)."""
+    counts = jnp.zeros((p,), jnp.int32).at[ids.reshape(-1)].add(
+        (vals != 0).reshape(-1).astype(jnp.int32))
+    need = min(H, p)
+
+    def halve(_, lohi):             # largest t with #(counts >= t) >= need
+        lo, hi = lohi
+        mid = (lo + hi + 1) // 2
+        ok = jnp.sum(counts >= mid) >= need
+        return jnp.where(ok, mid, lo), jnp.where(ok, hi, mid - 1)
+
+    t, _ = jax.lax.fori_loop(0, 32, halve, (jnp.int32(0), jnp.max(counts)))
+    tie = counts == t
+    head = (counts > t) | (tie & (_cumsum(tie) <= need - jnp.sum(
+        counts > t)))
+    j = jnp.arange(p, dtype=jnp.int32)
+    head_ids = jnp.full((need,), p, jnp.int32).at[
+        jnp.where(head, _cumsum(head) - 1, need)].set(j, mode="drop")
+    ranked = head_ids[jnp.argsort(-counts[head_ids], stable=True)]
+    tail_col = H + _cumsum(~head) - 1
+    return tail_col.at[ranked].set(jnp.arange(need, dtype=jnp.int32))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _pack_head(ids, vals, col, n_pad: int, H: int):
+    """The head (n_pad, H): each row's pairs in head columns, added up,
+    one field position at a time (a scatter into an array this size takes
+    half a minute to compile for a TPU)."""
+    pad = ((0, n_pad - ids.shape[0]), (0, 0))
+    c = jnp.pad(col[ids], pad, constant_values=H)
+    v = jnp.pad(vals, pad)
+    h = jnp.arange(H, dtype=jnp.int32)[None, :]
+
+    def add(k, head):
+        return head + jnp.where(c[:, k, None] == h, v[:, k, None], 0.0)
+
+    return jax.lax.fori_loop(0, ids.shape[1], add,
+                             jnp.zeros((n_pad, H), jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _merge_tail(ids, vals, col, H: int, tail_cols: int):
+    """Each row's tail entries as (tail columns, values), both (n, K),
+    sorted by column with a column's repeated pairs added into one entry,
+    then padding (value 0, column ``tail_cols``, one past the tail's
+    last); and each row's number of tail entries."""
+    c = col[ids] - H
+    key = jnp.where((c >= 0) & (vals != 0), c, tail_cols)
+    key, v = jax.lax.sort((key, vals), dimension=1, num_keys=1)
+    K = key.shape[1]
+    k = jnp.arange(K)
+    first = jnp.concatenate([jnp.ones_like(key[:, :1], bool),
+                             key[:, 1:] != key[:, :-1]], axis=1)
+    # the run of equal columns starting at a first entry ends before the
+    # next first entry: its sum is a difference of running sums
+    nxt = jnp.concatenate([jnp.where(first, k, K)[:, 1:],
+                           jnp.full_like(key[:, :1], K)], axis=1)
+    nxt = jax.lax.cummin(nxt, axis=1, reverse=True)
+    cs = jnp.cumsum(v, axis=1)
+    before = jnp.concatenate([jnp.zeros_like(v[:, :1]), cs[:, :-1]], axis=1)
+    run = jnp.take_along_axis(cs, nxt - 1, axis=1) - before
+    keep = first & (key < tail_cols) & (run != 0)
+    key, v = jax.lax.sort((jnp.where(keep, key, tail_cols),
+                           jnp.where(keep, run, 0.0)), dimension=1,
+                          num_keys=1)
+    return key, v, jnp.sum(keep, axis=1)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4))
+def _pad_tail(key, v, n_pad: int, K_tail: int, tail_cols: int):
+    """The ELL tail (n_pad, K_tail) from ``_merge_tail``'s rows, and the
+    entries of each tail column."""
+    pad = ((0, n_pad - key.shape[0]), (0, 0))
+    ids = jnp.pad(key[:, :K_tail], pad,
+                  constant_values=tail_cols).astype(jnp.int32)
+    counts = jnp.zeros((tail_cols,), jnp.int32).at[ids.reshape(-1)].add(
+        1, mode="drop")
+    return ids, jnp.pad(v[:, :K_tail], pad).astype(jnp.float32), counts
+
+
+# the working set of a HeadTailDesign: at most _WS_CAPACITY entries (12
+# bytes each on the device), read _WS_CHUNK at a time
+_WS_CAPACITY = 1 << 20
+_WS_CHUNK = 1 << 13
+
+
+def head_tail_design(rows, tile_size: int, head_features: int):
+    """(HeadTailDesign, DesignInfo) from ``SparseRows``, packed on the
+    device: the ``head_features`` most frequent features (a multiple of
+    ``tile_size``) dense, the rest in the tail, whose row lists are as wide
+    as the most tail nonzeros of any row.  Rows are padded to a multiple of
+    ``ops.DENSE_ROW_BLOCK`` (the fused kernels' row block).  The working
+    set holds at most ``_WS_CAPACITY`` entries (fewer where the tail has
+    fewer) of at most a 32nd as many columns; it starts as the whole tail.
+    The info's ``tail_counts`` (host) holds each tail column's entries,
+    which size working sets."""
+    ws_capacity, ws_chunk = _WS_CAPACITY, _WS_CHUNK
+    H, T = int(head_features), int(tile_size)
+    if H <= 0 or H % T:
+        raise ValueError(f"head_features={H} must be a positive multiple "
+                         f"of tile_size={T}")
+    n, p = rows.shape
+    ids = jnp.asarray(rows.ids, jnp.int32)
+    vals = jnp.asarray(rows.vals, jnp.float32)
+    n_pad = n + (-n) % ops.DENSE_ROW_BLOCK
+    tail_cols = max(p - H, 0)
+    tail_cols += (-tail_cols) % T
+    col = _frequency_columns(ids, vals, p, H)
+    head = _pack_head(ids, vals, col, n_pad, H)
+    key, v, tail_nnz = _merge_tail(ids, vals, col, H, tail_cols)
+    K_tail, E = int(jnp.max(tail_nnz)), int(jnp.sum(tail_nnz))
+    tail_ids, tail_vals, counts = _pad_tail(key, v, n_pad, K_tail, tail_cols)
+    del key, v
+    cap = max(ws_chunk, min(ws_capacity, E + (-E) % ws_chunk))
+    # the empty working set, from the program that gathers every later
+    # one, so that it compiles here, with the packing
+    ws = _gather_working_set(tail_ids, tail_vals,
+                             jnp.zeros((tail_cols,), bool), cap) \
+        if tail_cols else (jnp.full((cap,), n_pad, jnp.int32),
+                           jnp.zeros((cap,), jnp.int32),
+                           jnp.zeros((cap,), jnp.float32))
+    design = HeadTailDesign(
+        DenseDesign(head, T, _tile_major(head, T)), tail_ids, tail_vals,
+        *ws,
+        jnp.full((max(1, min(cap // 32, tail_cols)),), tail_cols, jnp.int32),
+        jnp.asarray(cap + 1, jnp.int32), T, tail_cols, ws_chunk)
+    return design, DesignInfo(shape=(n, p),
+                              col_of_feature=np.asarray(col, np.int64),
+                              tail_counts=np.asarray(counts, np.int64))
+
+
+# ---------------------------------------------------------------------------
 # streaming (out-of-core row chunks)
 # ---------------------------------------------------------------------------
 
@@ -771,6 +1155,7 @@ class DesignInfo:
     col_of_feature: Optional[np.ndarray] = None
     occupancy: float = 1.0
     n_bricks: int = 0
+    tail_counts: Optional[np.ndarray] = None   # HeadTailDesign's per column
 
     def unpack_beta(self, beta_packed: np.ndarray) -> np.ndarray:
         p = self.shape[1]
@@ -949,8 +1334,13 @@ def dense_design(X, tile_size: int):
 
 
 def as_design(X, tile_size: int, *, row_block: int = 256,
-              reorder: bool = True, info: Optional[DesignInfo] = None):
+              reorder: bool = True, info: Optional[DesignInfo] = None,
+              head_features: Optional[int] = None):
     """Coerce any supported input into (DesignMatrix, DesignInfo).
+
+    ``SparseRows`` pack into a ``HeadTailDesign`` with ``head_features``
+    dense columns, which they require: a brick build of hashed rows on the
+    host would not fit.
 
     A pre-built ``BlockSparseDesign`` must come with the ``DesignInfo`` its
     builder returned — the brick layout permutes columns (frequency packing
@@ -983,6 +1373,13 @@ def as_design(X, tile_size: int, *, row_block: int = 256,
                 "builder (pass design_info=...) so beta can be mapped back "
                 "to the original feature count/order")
         return X, info
+    if isinstance(X, SparseRows):
+        if head_features is None:
+            raise ValueError(
+                "SparseRows input needs DGLMNETConfig.head_features: the "
+                "number of most frequent features packed dense (a multiple "
+                "of tile_size); the rest form the sparse tail")
+        return head_tail_design(X, tile_size, head_features)
     if isinstance(X, SparseCOO):
         return build_block_sparse(X, tile_size, row_block=row_block,
                                   reorder=reorder)

@@ -7,6 +7,8 @@ VMEM-shaped tiles.  This module provides:
 
   * ``SparseCOO`` — host container with exact matvec/rmatvec (reference),
     row/col slicing, and densification into the brick layout.
+  * ``SparseRows`` — fixed-width (id, value) rows, the input of the
+    dense-head / sparse-tail layout for hashed multi-field data.
   * ``to_dense_blocks`` — the (features-sorted-by-frequency) brick packing
     used by the distributed driver, plus occupancy stats for the roofline
     model (occupancy is what decides whether densified bricks beat pure
@@ -73,6 +75,39 @@ class SparseCOO:
         inv = np.empty_like(perm)
         inv[perm] = np.arange(len(perm))
         return SparseCOO(self.rows, inv[self.cols], self.vals, self.shape)
+
+
+@dataclasses.dataclass
+class SparseRows:
+    """Rows with a fixed number K of (feature id, value) pairs each — the
+    LIBSVM layout of hashed multi-field data such as Criteo's (K = 39).
+
+    ``ids`` (n, K) int32 and ``vals`` (n, K) float32, host (numpy) or
+    device (jax) arrays; a pair with value 0 is padding, whatever its id.
+    Repeated ids within a row add up.  ``GLMSolver`` packs this input on
+    the device into a ``HeadTailDesign`` (``data/design.py``)."""
+    ids: object
+    vals: object
+    n_features: int
+
+    @property
+    def shape(self):
+        return (int(self.ids.shape[0]), int(self.n_features))
+
+    def matvec(self, beta):
+        """X @ beta as a gather-sum over each row's pairs (jnp, so device
+        inputs stay on the device)."""
+        import jax.numpy as jnp
+        beta = jnp.asarray(beta, jnp.float32)
+        return jnp.sum(jnp.asarray(self.vals, jnp.float32)
+                       * beta[jnp.asarray(self.ids)], axis=1)
+
+    def to_coo(self) -> SparseCOO:
+        """Host ``SparseCOO`` of the nonzero pairs (duplicates summed)."""
+        ids, vals = np.asarray(self.ids), np.asarray(self.vals, np.float32)
+        r, k = np.nonzero(vals != 0)
+        return SparseCOO(r.astype(np.int64), ids[r, k].astype(np.int64),
+                         vals[r, k], self.shape).dedupe()
 
 
 def to_dense_blocks(X: SparseCOO, tile_size: int, *, reorder: bool = True):

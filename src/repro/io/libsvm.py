@@ -30,7 +30,8 @@ Chunks come out in two forms sharing one parse:
 
 ``to_design`` wires straight into ``StreamingDesign`` (optionally through
 ``io/prefetch.py``'s background queue); ``to_coo`` materializes a
-``SparseCOO`` for in-memory fits (the parity baseline in tests).
+``SparseCOO`` for in-memory fits (the parity baseline in tests), and
+``to_rows`` fixed-width ``SparseRows`` for a head/tail fit.
 """
 from __future__ import annotations
 
@@ -42,7 +43,7 @@ from typing import Optional
 
 import numpy as np
 
-from repro.data.sparse import SparseCOO
+from repro.data.sparse import SparseCOO, SparseRows
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -335,6 +336,25 @@ class LibsvmReader:
         return SparseCOO(np.concatenate(rows), np.concatenate(cols),
                          np.concatenate(vals).astype(np.float32),
                          (self.n_rows, self.n_features)).dedupe()
+
+    def to_rows(self) -> SparseRows:
+        """Whole-file ``SparseRows``: every row's (index, value) pairs,
+        padded with value 0 to the widest row's count — the in-memory input
+        of a head/tail fit for a file with a fixed number of nonzeros a
+        row, such as the LIBSVM ``criteo`` set (39)."""
+        chunks = [self.chunk(i) for i in range(self.n_chunks)]
+        width = max(c.shape[1] for c, _ in chunks)
+        pad = lambda a, c: np.pad(a, ((0, 0), (0, width - c.shape[1])))
+        ids = np.concatenate([pad(np.where(c >= 0, c, 0), c)
+                              for c, _ in chunks])
+        vals = [pad(np.where(c >= 0, v, 0.0), c) for c, v in chunks]
+        if ids.size and ids.max() >= self.n_features:
+            raise ValueError(
+                f"{self.path} has feature index {int(ids.max())} beyond the "
+                f"n_features={self.n_features} cap")
+        return SparseRows(ids.astype(np.int32),
+                          np.concatenate(vals).astype(np.float32),
+                          self.n_features)
 
     def to_design(self, tile_size: int, *, hasher=None,
                   interactions: int = 0, prefetch: bool = True,

@@ -370,12 +370,17 @@ def _pack_rows(Xt3, *vecs):
 
 
 def fused_ls(design, y, xb, dbeta, alphas, family, *, weights=None,
-             offset=None, precision="fp32", backend=None):
+             offset=None, precision="fp32", backend=None, xdb_base=None,
+             relative=False):
     """Fused launch 2 of the superstep: margin delta xdb = X·Δβ plus every
     line-search candidate's loss in one pass.  Returns (xdb (n,),
-    losses (K,)).  Non-dense designs and non-TPU backends compose the
-    design's matvec with the alpha_search oracle instead (the margin vector
-    round-trips once, which XLA fusion absorbs on CPU)."""
+    losses (K,)).  ``xdb_base`` (n,), when given, is a margin delta made
+    elsewhere (a head/tail design's tail) that the launch adds to X·Δβ
+    before it scores the candidates; ``relative`` makes each candidate's
+    number its CHANGE of the loss, summed row by row.  Non-dense designs
+    and non-TPU backends compose the design's matvec with the alpha_search
+    oracle instead (the margin vector round-trips once, which XLA fusion
+    absorbs on CPU)."""
     record_launch("fused_ls")
     fname = _family_name(family)
     backend = _resolve("fused_ls", backend,
@@ -386,27 +391,35 @@ def fused_ls(design, y, xb, dbeta, alphas, family, *, weights=None,
     if backend == "ref":
         if hasattr(design, "tiles3"):
             Xt3 = design.tiles3()
-            y_p, xb_p, w_p, off_p = _pad_rows(Xt3.shape[1], y, xb, weights,
-                                              offset)
+            y_p, xb_p, w_p, off_p, base_p = _pad_rows(
+                Xt3.shape[1], y, xb, weights, offset, xdb_base)
             xdb, losses = ref.fused_ls_dense(
                 Xt3, y_p, xb_p, dbeta, w_p, alphas, fname, offset=off_p,
-                precision=precision)
+                precision=precision, xdb_base=base_p, relative=relative)
             xdb = xdb[:n]
         else:
             xdb = design.matvec(dbeta)
+            if xdb_base is not None:
+                xdb = xdb + xdb_base
             losses = ref.alpha_search(y, xb, xdb, weights, alphas, fname,
-                                      offset=offset)
+                                      offset=offset, relative=relative)
         return xdb, losses
     Xt3 = design.tiles3()
     T = design.tile_size
     nt = dbeta.shape[0] // T
     if offset is not None:
         xb = xb + offset
-    (y2, xb2, w_user), pad_mask = _pack_rows(Xt3, y, xb, weights)
+    if xdb_base is None:
+        (y2, xb2, w_user), pad_mask = _pack_rows(Xt3, y, xb, weights)
+        base = ()
+    else:
+        (y2, xb2, w_user, base2), pad_mask = _pack_rows(Xt3, y, xb, weights,
+                                                        xdb_base)
+        base = (base2,)
     xdb2, losses = margin_ls_pallas(
         Xt3, dbeta.reshape(nt, T), y2, xb2, w_user * pad_mask, alphas,
-        family=fname, block_n=DENSE_ROW_BLOCK, precision=precision,
-        interpret=_interpret())
+        *base, family=fname, block_n=DENSE_ROW_BLOCK, precision=precision,
+        relative=relative, interpret=_interpret())
     return xdb2.reshape(-1)[:n], losses
 
 

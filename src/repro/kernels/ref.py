@@ -116,8 +116,10 @@ def multinomial_stats(y, margins, weights=None, offset=None):
 # alpha_search: K-candidate line-search objective sweep in one data pass.
 # ---------------------------------------------------------------------------
 
-def alpha_search(y, xb, xdb, weights, alphas, family, offset=None):
-    """losses[k] = sum_i weights_i * l(y_i, xb_i + o_i + alphas[k] * xdb_i).
+def alpha_search(y, xb, xdb, weights, alphas, family, offset=None,
+                 relative=False):
+    """losses[k] = sum_i weights_i * l(y_i, xb_i + o_i + alphas[k] * xdb_i),
+    less sum_i weights_i * l(y_i, xb_i + o_i) row by row with ``relative``.
 
     Shapes: y, xb, xdb, weights[, offset]: (n,);  alphas: (K,);  out: (K,).
     """
@@ -126,6 +128,8 @@ def alpha_search(y, xb, xdb, weights, alphas, family, offset=None):
         xb = xb + offset
     m = xb[None, :] + alphas[:, None] * xdb[None, :]        # (K, n)
     loss, _, _ = fam.stats(y[None, :], m)
+    if relative:
+        loss = loss - fam.stats(y, xb)[0][None, :]
     return jnp.sum(loss * weights[None, :], axis=-1)
 
 
@@ -239,17 +243,21 @@ def fused_stats_gram_bricks(b3, rows, valid, y, xb, weights, family,
 
 
 def fused_ls_dense(Xt3, y, xb, dbeta, weights, alphas, family, offset=None,
-                   precision="fp32"):
+                   precision="fp32", xdb_base=None, relative=False):
     """Oracle for the fused margin→line-search launch: apply the margin
-    delta (xdb = XΔβ, accumulated over tiles) and evaluate every candidate
-    step's loss in the same pass.  Returns (xdb (n,), losses (K,))."""
+    delta (xdb = XΔβ, accumulated over tiles, plus ``xdb_base`` when
+    given) and evaluate every candidate step's loss in the same pass.
+    Returns (xdb (n,), losses (K,))."""
     nt, n, T = Xt3.shape
     dt = _acc_dtype(precision)
     dr = dbeta.reshape(nt, T).astype(dt)
     xdb = jnp.sum(jnp.matmul(Xt3.astype(dt), dr[:, :, None],
                              preferred_element_type=jnp.float32)[..., 0],
                   axis=0)
-    losses = alpha_search(y, xb, xdb, weights, alphas, family, offset=offset)
+    if xdb_base is not None:
+        xdb = xdb + xdb_base
+    losses = alpha_search(y, xb, xdb, weights, alphas, family, offset=offset,
+                          relative=relative)
     return xdb, losses
 
 

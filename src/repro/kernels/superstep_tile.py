@@ -19,8 +19,9 @@ The two kernels here collapse that chain to TWO launches:
 
 * ``margin_ls_pallas`` — grid ``(nb, nt)`` (row-major).  For each row block
   it accumulates the margin delta xdb = X·Δβ over tiles in a VMEM-resident
-  block, and at the last tile evaluates every line-search candidate's loss
-  against that block — xdb never round-trips HBM between the margin apply
+  block (from an optional additive base: the sparse tail's delta of a
+  head/tail design), and at the last tile evaluates every line-search
+  candidate's loss against that block — xdb never round-trips HBM between the margin apply
   and the candidate sweep.  The candidate losses accumulate element-wise:
   a VMEM scratch holds one (8, 128) tile per candidate (a sublane-reduced
   row past ``_LS_ACC_BUDGET``), each row block adds its masked losses into
@@ -210,15 +211,22 @@ def _ls_acc_rows(K, br):
     return br if K * br * 128 * 4 <= _LS_ACC_BUDGET else 1
 
 
-def _accumulate_candidates(alphas_ref, acc_ref, y, xb, xdb, mask, *, family):
+def _accumulate_candidates(alphas_ref, acc_ref, y, xb, xdb, mask, *, family,
+                           relative=False):
     """acc[k] += mask · l(y, xb + alphas[k]·xdb) for every candidate k:
     element-wise, no cross-lane reduction.  ``_LS_UNROLL`` candidates per
-    loop iteration, so one candidate's exp/log overlaps the next's."""
+    loop iteration, so one candidate's exp/log overlaps the next's.  With
+    ``relative`` each row adds its loss CHANGE, less mask · l(y, xb): the
+    sums are then the candidates' changes of the loss, rounded at their
+    own size rather than at the whole loss's."""
     loss = _LOSS[family]
     K, rows = alphas_ref.shape[0], acc_ref.shape[1]
+    base = mask * loss(y, xb) if relative else None
 
     def one(k):
         lk = mask * loss(y, xb + alphas_ref[k] * xdb)
+        if base is not None:
+            lk = lk - base
         if rows != lk.shape[0]:
             lk = jnp.sum(lk, axis=0, keepdims=True)
         acc_ref[k] += lk
@@ -234,12 +242,16 @@ def _accumulate_candidates(alphas_ref, acc_ref, y, xb, xdb, mask, *, family):
 
 
 def _margin_ls_kernel(alphas_ref, Xt_ref, db_ref, y_ref, xb_ref, mask_ref,
-                      xdb_ref, out_ref, acc_ref, *, family, precision):
+                      *refs, family, precision, relative):
     """Grid step (i, t): add tile t's share of row block i's margin delta
-    to xdb; at the block's last tile add every candidate's masked loss
-    element-wise into ``acc_ref`` (K, rows, 128), zeroed at step (0, 0);
-    at the launch's last step reduce ``acc_ref`` into the (1, K) output,
-    which is written only there."""
+    to xdb, which starts from zero or, with the optional base operand
+    (``refs`` then leads with it), from the base's block; at the block's
+    last tile add every candidate's masked loss element-wise into
+    ``acc_ref`` (K, rows, 128), zeroed at step (0, 0); at the launch's
+    last step reduce ``acc_ref`` into the (1, K) output, which is written
+    only there."""
+    base_ref = refs[0] if len(refs) == 4 else None
+    xdb_ref, out_ref, acc_ref = refs[-3:]
     i = pl.program_id(0)
     t = pl.program_id(1)
     nb = pl.num_programs(0)
@@ -251,7 +263,8 @@ def _margin_ls_kernel(alphas_ref, Xt_ref, db_ref, y_ref, xb_ref, mask_ref,
 
     @pl.when(t == 0)
     def _init_xdb():
-        xdb_ref[...] = jnp.zeros_like(xdb_ref)
+        xdb_ref[...] = jnp.zeros_like(xdb_ref) if base_ref is None \
+            else base_ref[...]
 
     # (1, T) · (block_n, T)ᵀ → (1, block_n): the margin delta as one lane
     # row, folded back into the (R, 128) vector block row by row
@@ -265,7 +278,8 @@ def _margin_ls_kernel(alphas_ref, Xt_ref, db_ref, y_ref, xb_ref, mask_ref,
     @pl.when(t == nt - 1)
     def _linesearch():
         _accumulate_candidates(alphas_ref, acc_ref, y_ref[...], xb_ref[...],
-                               xdb_ref[...], mask_ref[...], family=family)
+                               xdb_ref[...], mask_ref[...], family=family,
+                               relative=relative)
 
     # one cross-lane reduction per candidate for the whole launch: sublanes
     # on the VPU, then lanes on the MXU, as ones(1, 128) · sumsᵀ, which lays
@@ -280,13 +294,17 @@ def _margin_ls_kernel(alphas_ref, Xt_ref, db_ref, y_ref, xb_ref, mask_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("family", "block_n", "precision",
-                                             "interpret"))
-def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, *, family,
-                     block_n=1024, precision="fp32", interpret=True):
+                                             "relative", "interpret"))
+def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, base2=None, *,
+                     family, block_n=1024, precision="fp32", relative=False,
+                     interpret=True):
     """Fused launch 2 of the superstep: margin delta + candidate loss sweep.
 
     Xt3: (nt, n_pad, T); dbeta_r: (nt, T); y2/xb2/mask2: (R, 128) with
-    R * 128 == n_pad; alphas: (K,) candidate step sizes.
+    R * 128 == n_pad; alphas: (K,) candidate step sizes; base2: optional
+    (R, 128) margin delta added to X·Δβ before the candidates are scored
+    (a seventh operand; without it the launch has six).  ``relative``
+    returns each candidate's change of the loss (``_accumulate_candidates``).
     Returns (xdb2 (R, 128), losses (K,)).
     """
     nt, n_pad, T = Xt3.shape
@@ -302,14 +320,14 @@ def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, *, family,
     vspec = pl.BlockSpec((br, C), lambda i, t: (i, 0))
     out = pl.pallas_call(
         functools.partial(_margin_ls_kernel, family=family,
-                          precision=precision),
+                          precision=precision, relative=relative),
         grid=(nb, nt),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_n, T), lambda i, t: (t, i, 0)),
             pl.BlockSpec((1, 1, T), lambda i, t: (t, 0, 0)),
             vspec, vspec, vspec,
-        ],
+        ] + ([] if base2 is None else [vspec]),
         out_specs=[vspec, pl.BlockSpec((1, K), lambda i, t: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((R, C), f32),
                    jax.ShapeDtypeStruct((1, K), f32)],
@@ -319,5 +337,6 @@ def margin_ls_pallas(Xt3, dbeta_r, y2, xb2, mask2, alphas, *, family,
         interpret=interpret,
     )(alphas.astype(f32), Xt3.astype(f32),
       dbeta_r.astype(f32).reshape(nt, 1, T), y2.astype(f32),
-      xb2.astype(f32), mask2.astype(f32))
+      xb2.astype(f32), mask2.astype(f32),
+      *(() if base2 is None else (base2.astype(f32),)))
     return out[0], out[1][0]
