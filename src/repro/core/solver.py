@@ -352,12 +352,15 @@ class GLMSolver:
         self._ws_cache = None           # (key, design, entries) _round_design
         # host-side sweep launch bookkeeping (active-set-shaped launches,
         # DESIGN.md §8): tiles the CD sweep actually processed vs skipped
-        # because every coordinate was screened out.  In-memory fits only.
+        # because every coordinate was screened out, and the Gram blocks
+        # the fused kernel mirrored rather than contracted in the live
+        # ones.  In-memory fits only.
         # The λ-path loop counts the λ points it finished and the ``_run``
         # calls (KKT rounds) it made for them.
         self.launch_stats = {"supersteps": 0, "sweep_tile_launches": 0,
                              "sweep_tiles_skipped": 0, "lambdas": 0,
-                             "kkt_rounds": 0, "tail_entries": 0}
+                             "kkt_rounds": 0, "tail_entries": 0,
+                             "gram_blocks_mirrored": 0}
         # convergence event stream (repro.obs, DESIGN.md §12): auto-opened
         # next to the trace shards when tracing targets a directory, or
         # attached explicitly via set_convergence_stream().
@@ -1166,10 +1169,13 @@ class GLMSolver:
             live_tiles = int((act[:swept_cols].reshape(
                 total_tiles, cfg.tile_size).max(axis=1) > 0).sum())
             live_active = int((act > 0).sum())
+        fused = cfg.fuse_superstep and cfg.coupling == "jacobi" and \
+            self.axis_data is None and self.axis_model is None
         shaped = active is not None and self.axis_data is None and (
-            cfg.coupling == "gauss-seidel"
-            or (cfg.fuse_superstep and cfg.coupling == "jacobi"
-                and self.axis_model is None))
+            cfg.coupling == "gauss-seidel" or fused)
+        mirrored = ops.fused_gram_blocks_mirrored(
+            X.head if isinstance(X, HeadTailDesign) else X, cfg.family,
+            cfg.kernel_backend) if fused else 0
 
         history = {k: [] for k in _HISTORY_KEYS}
         f_prev, converged, it = np.inf, False, 0
@@ -1204,6 +1210,8 @@ class GLMSolver:
             if shaped:
                 self.launch_stats["sweep_tiles_skipped"] += \
                     total_tiles - live_tiles
+            self.launch_stats["gram_blocks_mirrored"] += \
+                (live_tiles if shaped else total_tiles) * mirrored
             # lint: allow SYNC001 — mh is the host copy fetched in dispatch
             f = float(mh["f"])
             for k in history:

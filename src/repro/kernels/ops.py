@@ -30,6 +30,7 @@ from repro.kernels.glm_stats import _STATS as _PALLAS_STATS
 from repro.kernels.glm_stats import glm_stats_pallas
 from repro.kernels.predict_tile import _LINKS as _PALLAS_LINKS
 from repro.kernels.predict_tile import predict_tile_pallas
+from repro.kernels.superstep_tile import gram_blocks_mirrored
 from repro.kernels.superstep_tile import margin_ls_pallas
 from repro.kernels.superstep_tile import stats_gram_solve_pallas
 from repro.kernels.tile_gram import tile_gram_pallas
@@ -270,6 +271,16 @@ def _fused_fallback(design, fname):
         return ("fused superstep on a non-dense design composes the jnp "
                 "oracle")
     return _no_pallas_body(fname)
+
+
+def fused_gram_blocks_mirrored(design, family, backend=None):
+    """Gram blocks per live tile that ``fused_stats_sweep`` on ``design``
+    mirrors rather than contracts: the Pallas kernel's, none where the
+    launch composes the jnp oracle."""
+    if (backend or default_backend()) == "ref" or \
+            _fused_fallback(design, _family_name(family)):
+        return 0
+    return gram_blocks_mirrored(design.tile_size)
 
 
 def _params(mu, nu, lam1, lam2):

@@ -15,7 +15,16 @@ The two kernels here collapse that chain to TWO launches:
   and T-gradient in VMEM; at the tile's last row block it runs the
   sequential soft-threshold solve (same chain as cd_tile_solve.py) on the
   VMEM-resident Gram.  ``s`` and ``w`` never round-trip HBM between the
-  stats and the Gram pass.
+  stats and the Gram pass.  The Gram Xᵀ diag(w) X is symmetric, so each
+  row block contracts only its upper block triangle: 128-column block b
+  of X against the first (b + 1)·128 rows of the weighted Xᵀ, 3 of 4
+  blocks' work at T = 256 and 10 of 16 at T = 512 (a tile 128 wide, or
+  of a width 128 does not divide, is one block).  Before the chain, the
+  tile's last row block writes each lower block as its mirror's
+  transpose and keeps each diagonal block's upper triangle, so the chain
+  and ``G_all`` see an exactly symmetric Gram.  At ``Precision.HIGHEST``
+  the v5e MXU runs an f32 contraction as six bf16 passes, so the Gram's
+  products, not the X stream, bound this kernel.
 
 * ``margin_ls_pallas`` — grid ``(nb, nt)`` (row-major).  For each row block
   it accumulates the margin delta xdb = X·Δβ over tiles in a VMEM-resident
@@ -40,6 +49,8 @@ caller masks Δβ by tile liveness regardless).
 Mixed precision (tentpole c): ``precision="bf16"`` casts the Gram/margin
 matmul INPUTS to bf16 with f32 accumulation (``preferred_element_type``);
 the link stats, the solve chain, and the Armijo loss sums stay f32.
+Mosaic lowers only DEFAULT and HIGHEST dot precision (HIGH raises), so
+these two are the modes there are.
 
 Shapes follow ops._pack_2d: vectors as (R, 128) with a mask folding weights
 and padding; rows are padded to a multiple of ``block_n`` examples (1024 by
@@ -62,6 +73,7 @@ from repro.kernels.glm_stats import _LOSS, _STATS
 
 MU, NU, LAM1, LAM2 = 0, 1, 2, 3  # params (4,) SMEM layout, as cd_tile_solve
 _HIGHEST = jax.lax.Precision.HIGHEST
+_MXU = 128             # the MXU's width: the Gram's square block
 # scoped VMEM of the Gram kernel: at T = 512 its double-buffered design
 # block, Gram block and the transposed operand need ~20 MiB, above Mosaic's
 # 16 MiB default (a v5e core has 128 MiB of VMEM)
@@ -85,6 +97,36 @@ def _lane_row(v):
     """(R, 128) → (1, R·128): row-major lane concatenation of the rows —
     example ``r·128 + l`` of the block lands in lane ``r·128 + l``."""
     return jnp.concatenate([v[r:r + 1, :] for r in range(v.shape[0])], axis=1)
+
+
+def _gram_block(T):
+    """Width of the Gram's square blocks: the MXU's 128 where it divides
+    the tile, else the whole tile (one block, nothing to mirror)."""
+    return _MXU if T % _MXU == 0 else T
+
+
+def gram_blocks_mirrored(T):
+    """Blocks of one tile's Gram that ``stats_gram_solve_pallas`` mirrors
+    rather than contracts: the b(b − 1)/2 below the diagonal, for b
+    blocks across the tile."""
+    b = T // _gram_block(T)
+    return b * (b - 1) // 2
+
+
+def _mirror_upper(G_ref, c):
+    """Complete the tile's Gram from its upper block triangle: each block
+    below the diagonal is its mirror's transpose, and each diagonal block
+    keeps its upper triangle, so the Gram is exactly symmetric."""
+    T = G_ref.shape[-1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    for b in range(T // c):
+        rows = slice(b * c, (b + 1) * c)
+        D = G_ref[0, rows, rows]
+        G_ref[0, rows, rows] = jnp.where(ii <= jj, D, D.T)
+        for a in range(b):
+            G_ref[0, rows, a * c:(a + 1) * c] = \
+                G_ref[0, a * c:(a + 1) * c, rows].T
 
 
 def _stats_gram_solve_kernel(sel_ref, Xt_ref, y_ref, xb_ref, mask_ref,
@@ -113,20 +155,28 @@ def _stats_gram_solve_kernel(sel_ref, Xt_ref, y_ref, xb_ref, mask_ref,
         G_ref[...] = jnp.zeros_like(G_ref)
         g_ref[...] = jnp.zeros_like(g_ref)
 
+    T = G_ref.shape[-1]
+    c = _gram_block(T)
+
     @pl.when(live)
     def _accumulate():
         X = Xt_ref[0]                      # (block_n, T)
-        # the row weights scale the lanes of Xᵀ: G += Xᵀ diag(w) X
+        # the row weights scale the lanes of Xᵀ: G += Xᵀ diag(w) X, by
+        # column blocks, each against the rows of Xᵀ up to its diagonal
+        # block: only the upper block triangle is contracted
         (wXt, Xc, sv), prec = _matmul_inputs(
             precision, X.T * _lane_row(w), X, _lane_row(s))
-        G_ref[0] += jnp.dot(wXt, Xc, precision=prec,
-                            preferred_element_type=jnp.float32)
+        for b in range(T // c):
+            hi = (b + 1) * c
+            G_ref[0, :hi, b * c:hi] += jnp.dot(
+                wXt[:hi], Xc[:, b * c:hi], precision=prec,
+                preferred_element_type=jnp.float32)
         g_ref[0] += jnp.dot(sv, Xc, precision=prec,
                             preferred_element_type=jnp.float32)
 
     @pl.when(i == nb - 1)
     def _solve():
-        T = G_ref.shape[-1]
+        _mirror_upper(G_ref, c)
         ii = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
         jj = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
         h = jnp.sum(jnp.where(ii == jj, G_ref[0], 0.0), axis=0,

@@ -124,6 +124,58 @@ def test_fused_pallas_kernels_match_oracle(family):
                                rtol=1e-5, atol=1e-3)
 
 
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+@pytest.mark.parametrize("T", [128, 256, 512])
+def test_stats_gram_solve_blocked_gram(T, precision):
+    """``stats_gram_solve_pallas`` (interpret mode) contracts only the
+    upper 128-blocks of each tile's Gram and mirrors the rest: ``G_all``
+    matches the oracle's full Gram and is bitwise symmetric, each live
+    tile's Δβ is the oracle's chain on the oracle's Gram, and a dead tile
+    returns zeros — over two 1024-row blocks with a masked tail."""
+    from repro.kernels import ref, superstep_tile
+    rng = np.random.default_rng(T)
+    nt, n_pad, n = 3, 2048, 2048 - 300
+    Xt3 = (0.2 * rng.normal(size=(nt, n_pad, T))).astype(np.float32)
+    Xt3[:, n:] = 0.0
+    y = rng.choice([-1.0, 1.0], n_pad).astype(np.float32)
+    xb = (0.5 * rng.normal(size=n_pad)).astype(np.float32)
+    mask = (np.arange(n_pad) < n).astype(np.float32)
+    beta = (0.3 * rng.normal(size=(nt, T))
+            * (rng.random((nt, T)) < 0.3)).astype(np.float32)
+    penf = rng.uniform(0.5, 2.0, (nt, T)).astype(np.float32)
+    mu, nu, lam1, lam2 = 1.0, 1e-6, 0.1, 0.05
+    sel = jnp.asarray([0, 2, 1, 2], jnp.int32)     # tile 1 dead
+    pack = lambda v: jnp.asarray(v.reshape(-1, 128))
+    _, _, _, G, g, dbeta = superstep_tile.stats_gram_solve_pallas(
+        sel, jnp.asarray(Xt3), pack(y), pack(xb), pack(mask),
+        jnp.asarray(beta), jnp.asarray(penf),
+        jnp.asarray([mu, nu, lam1, lam2], jnp.float32),
+        family="logistic", precision=precision, interpret=True)
+    G, g, dbeta = np.asarray(G), np.asarray(g), np.asarray(dbeta)
+    _, _, _, G_ref, g_ref = ref.fused_stats_gram_dense(
+        jnp.asarray(Xt3), jnp.asarray(y), jnp.asarray(xb), jnp.asarray(mask),
+        "logistic", precision=precision)
+    # the kernel's Gram is the oracle's upper triangle, mirrored: with
+    # bf16 inputs the oracle's own two triangles differ by bf16 rounding
+    # (row weights round with the left operand)
+    upper = np.triu(np.ones((T, T), bool))
+    G_ref = jnp.where(upper, G_ref, jnp.swapaxes(G_ref, 1, 2))
+    assert (G == np.swapaxes(G, 1, 2)).all()
+    assert not G[1].any() and not g[1].any() and not dbeta[1].any()
+    for t in (0, 2):
+        np.testing.assert_allclose(G[t], np.asarray(G_ref[t]), rtol=1e-5,
+                                   atol=1e-4)
+        np.testing.assert_allclose(g[t], np.asarray(g_ref[t]), rtol=1e-5,
+                                   atol=1e-4)
+        want = ref.cd_tile_solve(G_ref[t], g_ref[t], jnp.diagonal(G_ref[t]),
+                                 jnp.asarray(beta[t]), jnp.zeros((T,)), mu,
+                                 nu, lam1, lam2, penf=jnp.asarray(penf[t]))
+        np.testing.assert_allclose(dbeta[t], np.asarray(want), atol=1e-4)
+        assert np.abs(dbeta[t]).max() > 0
+    assert superstep_tile.gram_blocks_mirrored(T) == {
+        128: 0, 256: 1, 512: 6}[T]
+
+
 def _ls_parity_problem(family, n, p, T, seed):
     """A dense design and a line-search direction from the oracle's tile
     solves, under sample weights and an offset, with moderate margins."""
@@ -230,6 +282,22 @@ def test_screened_tiles_cost_zero_sweep_launches():
                                                        tile_size=16))
     s2.fit_path(n_lambdas=8, lam_ratio=1e-2)
     assert s2.launch_stats["sweep_tiles_skipped"] == 0
+
+
+@pytest.mark.parametrize("backend,per_tile", [("pallas", 1), ("ref", 0)])
+def test_gram_blocks_mirrored_counts_live_tiles(backend, per_tile):
+    """At T = 256 the fused Pallas kernel mirrors one 128-block of each
+    live tile's Gram a superstep: along a screened path the counter is
+    the tiles it launched.  The oracle contracts every block."""
+    ds = synthetic.make_dense(n=300, p=768, k_true=6, seed=14)
+    s = GLMSolver(ds.train.X, ds.train.y, config=DGLMNETConfig(
+        tile_size=256, coupling="jacobi", max_outer=8,
+        kernel_backend=backend))
+    s.fit_path(n_lambdas=3, lam_ratio=0.1)
+    st = s.launch_stats
+    assert st["sweep_tiles_skipped"] > 0, st
+    assert st["gram_blocks_mirrored"] == \
+        per_tile * st["sweep_tile_launches"], st
 
 
 def test_runtime_active_changes_do_not_recompile():

@@ -122,7 +122,9 @@ def _fused_shapes(T, n_pad, p):
 @pytest.mark.parametrize("T,n_pad,p,precision",
                          [(256, DENSE_NPAD, 2048, "fp32"),
                           (256, DENSE_NPAD, 2048, "bf16"),
-                          (512, 32768, 65536, "fp32")])
+                          (512, 32768, 65536, "fp32"),
+                          # one 128-block a tile: the unblocked Gram
+                          (128, DENSE_NPAD, 2048, "fp32")])
 def test_stats_gram_solve(one_chip, T, n_pad, p, precision):
     nt, R = _fused_shapes(T, n_pad, p)
     fn = lambda *a: stats_gram_solve_pallas(
