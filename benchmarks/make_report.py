@@ -1,7 +1,6 @@
-"""Generate the EXPERIMENTS.md §Dry-run / §Roofline tables from the
-results/dryrun JSON records, and the §Benchmarks section from the committed
+"""Generate the §Benchmarks section from the committed
 results/benchmarks/*.json records (kernels, fig2_4_l1, path_bench,
-cv_bench, ...).
+cv_bench, ...) and the §Static analysis gate row.
 
     PYTHONPATH=src:. python -m benchmarks.make_report > /tmp/tables.md
 """
@@ -10,7 +9,6 @@ from __future__ import annotations
 import json
 import pathlib
 
-RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results" / "dryrun"
 BENCH_RESULTS = pathlib.Path(__file__).resolve().parents[1] / "results" \
     / "benchmarks"
 
@@ -51,71 +49,6 @@ BENCH_COLUMNS = {
                       "max_err_bound", "max_abs_err_vs_oracle",
                       "n_active", "compiled_shapes"],
 }
-
-ARCH_ORDER = ["gemma3-12b", "qwen2.5-32b", "phi4-mini-3.8b",
-              "mistral-large-123b", "zamba2-1.2b", "deepseek-v2-lite-16b",
-              "mixtral-8x7b", "xlstm-1.3b", "llama-3.2-vision-11b",
-              "whisper-tiny", "dglmnet"]
-SHAPE_ORDER = ["train_4k", "prefill_32k", "decode_32k", "long_500k",
-               "glm_web", "glm_tall"]
-
-
-def load(mesh_tag):
-    recs = {}
-    d = RESULTS / mesh_tag
-    if not d.exists():
-        return recs
-    for f in d.glob("*.json"):
-        r = json.loads(f.read_text())
-        recs[(r["arch"], r["shape"])] = r
-    return recs
-
-
-def fmt_s(x):
-    if x >= 1.0:
-        return f"{x:.2f}s"
-    return f"{x * 1e3:.1f}ms"
-
-
-def roofline_table(recs, mesh_tag):
-    lines = [
-        f"### Mesh {mesh_tag}",
-        "",
-        "| arch | shape | compute | memory | collective | dominant | "
-        "MODEL_FLOPs | useful ratio | peak GB/chip | status |",
-        "|---|---|---|---|---|---|---|---|---|---|",
-    ]
-    for arch in ARCH_ORDER:
-        for shape in SHAPE_ORDER:
-            r = recs.get((arch, shape))
-            if r is None:
-                continue
-            if r["status"] == "skipped":
-                lines.append(f"| {arch} | {shape} | — | — | — | — | — | — |"
-                             f" — | skipped: {r['reason'].split(':')[-1].strip()} |")
-                continue
-            if r["status"] != "ok":
-                lines.append(f"| {arch} | {shape} | — | — | — | — | — | — |"
-                             f" — | {r['status']} |")
-                continue
-            ro = r["roofline"]
-            mf = r.get("model_flops")
-            ur = r.get("useful_compute_ratio")
-            peak = r.get("memory", {}).get("peak_bytes_est", 0) / 1e9
-            lines.append(
-                f"| {arch} | {shape} | {fmt_s(ro['compute_s'])} | "
-                f"{fmt_s(ro['memory_s'])} | {fmt_s(ro['collective_s'])} | "
-                f"**{ro['dominant']}** | {mf:.2e} | "
-                f"{ur:.2f} | {peak:.1f} | ok |")
-    return "\n".join(lines)
-
-
-def summary(recs):
-    n_ok = sum(r["status"] == "ok" for r in recs.values())
-    n_skip = sum(r["status"] == "skipped" for r in recs.values())
-    n_fail = len(recs) - n_ok - n_skip
-    return n_ok, n_skip, n_fail
-
 
 def _fmt_cell(v):
     if isinstance(v, float):
@@ -190,14 +123,6 @@ def analysis_section() -> str:
 
 
 def main():
-    print("## Dry-run / Roofline")
-    print()
-    for mesh_tag in ("1x16x16", "2x16x16"):
-        recs = load(mesh_tag)
-        ok, skip, fail = summary(recs)
-        print(f"<!-- {mesh_tag}: ok={ok} skipped={skip} failed={fail} -->")
-        print(roofline_table(recs, mesh_tag))
-        print()
     section = benchmarks_section()
     if section:
         print(section)

@@ -8,11 +8,10 @@ package's own entry points (``GLMSolver``, ``repro.serve``).
 One chip (no arguments):
 
   (a) sparse λ-path, the paper's regime: logistic regression on a
-      ``BlockSparseDesign`` with the ``glm_sparse`` shape
-      (``configs/glm_webscale.py``) at one chip's share of its 16×16 mesh —
-      32,768 rows × 65,536 features, T = 512, row_block = 256, about 5 %
-      brick occupancy, Zipf feature popularity — fitted over a short
-      warm-started λ-path with the default (Gauss-Seidel) configuration:
+      ``BlockSparseDesign`` of 32,768 rows × 65,536 features, T = 512,
+      row_block = 256, about 5 % brick occupancy, Zipf feature popularity —
+      fitted over a short warm-started λ-path with the default
+      (Gauss-Seidel) configuration:
       ``tile_gram``, ``cd_tile_solve``, ``glm_stats``, ``alpha_search``.
   (b) dense control: the shape of the paper's epsilon set, 400,000 × 2,000
       correlated features, T = 256, ``coupling="jacobi"`` with the fused
@@ -21,7 +20,7 @@ One chip (no arguments):
       requests through a ``MicroBatcher`` in front of a ``ScoringEngine``
       (``predict_tile``).
 
-Four chips (``--chips 4``): the ``glm_sparse`` shape at four shards' worth
+Four chips (``--chips 4``): (a)'s sparse problem at four shards' worth
 (65,536 × 131,072) fitted on a (1, 4) mesh (the paper's 1-D feature split)
 and on (2, 2), each against the same problem fitted on one device in this
 process.  Its features are numbered so that consecutive popularity ranks
@@ -70,7 +69,7 @@ LAM_RATIO = 0.1     # λ_min / λ_max
 SHARDED_LAM_RATIO = 0.01
 MAX_OUTER = 100     # supersteps per λ at most
 
-# (a) and the four-chip phase: one chip's share of glm_sparse, and four's
+# (a); the four-chip phase doubles both
 SPARSE_ROWS, SPARSE_FEATURES = 32768, 65536
 SPARSE_TILE, ROW_BLOCK, AVG_NNZ = 512, 256, 16
 # (b): the epsilon set's shape
@@ -96,7 +95,7 @@ def sparse_problem(rows, features, seed):
     """Training split with ``rows`` rows (make_sparse keeps 80 %).  The
     popularity law is concentrated on a hot head of ~p/2730 features with a
     thin Zipf tail: about 5 % of the (row block × tile) bricks carry
-    nonzeros, the occupancy of configs/glm_webscale.py "glm_sparse"."""
+    nonzeros."""
     from repro.data import synthetic
     return synthetic.make_sparse(
         n=rows * 5 // 4, p=features, avg_nnz=AVG_NNZ, k_true=200,

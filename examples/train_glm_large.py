@@ -3,8 +3,7 @@ CPU box sustains): train a wide sparse logistic regression — ~1M features —
 with distributed d-GLMNET over 8 simulated feature-split nodes, with
 checkpointing every 10 supersteps and automatic resume.
 
-Scale knobs: N_EXAMPLES / N_FEATURES / devices; the same driver lowered on
-the (16,16) and (2,16,16) production meshes is results/dryrun/*/dglmnet__*.
+Scale knobs: N_EXAMPLES / N_FEATURES / devices.
 
     python examples/train_glm_large.py [--features 1048576] [--steps 200]
 """

@@ -7,7 +7,6 @@ Public API:
   fit, fit_sharded                    — DEPRECATED one-shot drivers (thin
                                         wrappers over a GLMSolver session)
   glm.FAMILIES                        — logistic / squared / probit / poisson
-  head_probe.fit_probe                — elastic-net GLM head on frozen LM features
 """
 from repro.core import glm  # noqa: F401
 

@@ -19,7 +19,7 @@ Contracts:
   * **CPU collectives** — cross-process collectives on the CPU backend
     need the gloo implementation; ``initialize()`` flips
     ``jax_cpu_collectives_implementation`` BEFORE the backend is created
-    (it must run before the first jax device query, like the dry-run's
+    (it must run before the first jax device query, like the
     XLA_FLAGS contract in ``launch/mesh.py``).
   * **global placement** — host arrays cannot be ``device_put`` onto a
     sharding that spans non-addressable devices; ``put_global`` routes

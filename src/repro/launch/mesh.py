@@ -1,44 +1,17 @@
-"""Production mesh construction.
+"""(data × model) mesh construction over an explicit device list.
 
-Defined as FUNCTIONS (not module constants) so importing this module never
-touches jax device state — the dry-run must set XLA_FLAGS before the first
-jax device query, and smoke tests must keep seeing 1 CPU device.
+A function, not a module constant, so importing this module never touches
+jax device state: multi-device callers set XLA_FLAGS before the first jax
+device query, and smoke tests must keep seeing 1 CPU device.
 """
 from __future__ import annotations
 
-import math
-
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: (16, 16) = 256 chips, axes (data, model).
-    Multi-pod: (2, 16, 16) = 512 chips, axes (pod, data, model)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = math.prod(shape)
-    devices = jax.devices()[:n]
-    if len(devices) < n:
-        raise RuntimeError(
-            f"need {n} devices for the production mesh, have "
-            f"{len(jax.devices())} — set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
-            f"importing jax (dryrun.py does this for you)")
-    import numpy as np
-    dev_array = np.asarray(devices).reshape(shape)
-    return jax.sharding.Mesh(dev_array, axes)
-
-
-def make_glm_mesh(n_data: int, n_model: int):
-    """Mesh for the d-GLMNET workload: rows × feature-blocks.
-    (1, M) reproduces the paper's layout exactly."""
-    return mesh_from_devices(jax.devices(), n_data, n_model)
 
 
 def mesh_from_devices(devices, n_data: int, n_model: int):
     """(data × model) mesh over an explicit device list.
 
-    The single-process callers above pass ``jax.devices()`` of one process;
     ``repro.dist.bootstrap`` passes the GLOBAL device list of a
     ``jax.distributed`` bring-up, producing a process-spanning mesh with
     the same axis names — everything downstream (shard_map supersteps,
@@ -55,9 +28,3 @@ def mesh_from_devices(devices, n_data: int, n_model: int):
     import numpy as np
     return jax.sharding.Mesh(np.asarray(devices).reshape(n_data, n_model),
                              ("data", "model"))
-
-
-# TPU v5e hardware constants for the roofline model (per chip)
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # B/s
-ICI_BW_PER_LINK = 50e9        # B/s per link

@@ -14,9 +14,6 @@ compiled-shape count — the record CI's serving smoke asserts on.
 
 ``--batch1`` serves every request as its own engine dispatch (the honest
 no-coalescing baseline) instead of micro-batching.
-
-This is the GLM serving entry point; ``repro.launch.serve`` is the
-unrelated LM-template decode-loop demo (see that module's docstring).
 """
 from __future__ import annotations
 

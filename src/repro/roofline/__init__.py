@@ -1,2 +1,1 @@
 from repro.roofline.hlo import analyze_hlo, HLOStats  # noqa: F401
-from repro.roofline.model import roofline_terms, model_flops  # noqa: F401

@@ -1,19 +1,13 @@
-"""Shared fixtures.  NOTE: XLA_FLAGS / device-count overrides are
-deliberately NOT set here — smoke tests must see the real single CPU
-device.  Multi-device tests run subprocesses (tests/progs/) that set
-XLA_FLAGS before importing jax."""
+"""Runner for the multi-device test programs in tests/progs/.
+
+XLA_FLAGS / device-count overrides are deliberately NOT set in the test
+process — smoke tests must see the real single CPU device.  Multi-device
+tests run the programs in subprocesses that set XLA_FLAGS before
+importing jax."""
 import os
 import pathlib
 import subprocess
 import sys
-
-import numpy as np
-import pytest
-
-# Property-test modules guard their hypothesis import themselves (like
-# test_kernels.py): with hypothesis installed they run the full generative
-# sweeps, without it they fall back to fixed-seed parametrizations — no
-# module is skipped at collection time anymore.
 
 REPO = pathlib.Path(__file__).resolve().parents[1]
 SRC = REPO / "src"
@@ -31,8 +25,3 @@ def run_prog(name: str, *args, devices: int = 8, timeout: int = 900):
     assert out.returncode == 0, \
         f"{name} failed:\nSTDOUT:\n{out.stdout[-4000:]}\nSTDERR:\n{out.stderr[-4000:]}"
     return out.stdout
-
-
-@pytest.fixture(scope="session")
-def rng():
-    return np.random.default_rng(0)

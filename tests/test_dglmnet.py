@@ -104,18 +104,6 @@ def test_poisson_family_fits():
     assert np.isfinite(res.beta).all()
 
 
-def test_head_probe_single_device():
-    """GLM head probe on synthetic 'frozen features' (the paper-technique ↔
-    LM integration point)."""
-    from repro.core import head_probe
-    ds = synthetic.make_dense(n=600, p=64, seed=9)
-    cfg = DGLMNETConfig(lam1=0.2, lam2=0.2, tile_size=16, max_outer=40)
-    res = head_probe.fit_probe(ds.train.X, ds.train.y, cfg)
-    p = np.asarray(head_probe.predict_proba(ds.test.X, res.beta))
-    acc = ((p > 0.5) == (ds.test.y > 0)).mean()
-    assert acc > 0.8, acc
-
-
 def test_fit_path_single_lambda_matches_cold_fit():
     """A fit_path evaluated at any single λ equals a cold fit at that λ
     (warm starts + screening must not change the solution)."""

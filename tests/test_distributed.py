@@ -1,19 +1,14 @@
 """Multi-device behaviour, run in subprocesses with fake CPU devices so the
-main pytest process keeps seeing exactly 1 device (see conftest)."""
+main pytest process keeps seeing exactly 1 device (see _progs)."""
 import pytest
 
-from conftest import run_prog
+from _progs import run_prog
 
 
 @pytest.mark.slow
 def test_distributed_glm_equivalence():
     out = run_prog("dist_glm", devices=8)
     assert "DIST_GLM_OK" in out
-
-
-def test_vocab_parallel_ce():
-    out = run_prog("dist_ce", devices=8)
-    assert "DIST_CE_OK" in out
 
 
 @pytest.mark.slow

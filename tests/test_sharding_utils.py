@@ -1,5 +1,5 @@
-"""Sharding/scale utilities: compression error bounds, ALB budget rule,
-TP padding rules for every assigned arch."""
+"""Sharding/scale utilities: compression error bounds and the ALB budget
+rule."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,8 +14,6 @@ except ImportError:          # the rest of this module runs without it
 
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import tp_pad_config
-from repro.configs.registry import ARCHS
 from repro.core import alb
 from repro.sharding import compat
 from repro.sharding.compress import psum_compressed
@@ -137,37 +135,3 @@ class TestALB:
         rng = np.random.default_rng(0)
         s = alb.sample_speeds(rng, np.ones(64))
         assert (s > 0).all()
-
-
-@pytest.mark.parametrize("name", sorted(ARCHS))
-def test_tp_padding_rules(name):
-    """Padded configs must divide the 16-way axis and keep integer GQA
-    grouping; unpadded dims stay untouched."""
-    cfg = ARCHS[name]
-    padded, pads = tp_pad_config(cfg, 16)
-    assert padded.n_heads % 16 == 0 or 16 % padded.n_heads == 0
-    assert (padded.n_kv_heads % 16 == 0 or 16 % padded.n_kv_heads == 0)
-    assert padded.n_heads % padded.n_kv_heads == 0
-    assert padded.vocab_size % 16 == 0
-    assert padded.n_heads >= cfg.n_heads
-    assert padded.vocab_size >= cfg.vocab_size
-    for field in ("d_model", "d_ff", "n_layers"):
-        assert getattr(padded, field) == getattr(cfg, field)
-
-
-def test_zero1_and_fsdp_sharding_choices():
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.models.lm import fsdp_param_sharding, zero1_sharding
-
-    from repro.sharding import compat
-    mesh = compat.make_mesh((1,), ("data",))
-    # zero1 picks the first free divisible dim
-    sds = jax.ShapeDtypeStruct((4, 7), jnp.float32,
-                               sharding=NamedSharding(mesh, P(None, None)))
-    sh = zero1_sharding(sds, mesh)
-    assert sh.spec[0] in (("data",), "data")
-    # fsdp falls back to replication when nothing divides
-    sh2 = fsdp_param_sharding((3, 5), mesh)
-    # data axis has size 1 -> everything divides; first dim chosen
-    assert sh2.spec[0] in (("data",), "data")

@@ -1,20 +1,9 @@
-"""End-to-end behaviour tests for the whole system: the paper's algorithm
-fitting real (synthetic) data to its oracle optimum, the LM trainer making
-loss progress with checkpoint/restart, and a serve loop decoding tokens."""
-import numpy as np
-import pytest
-
-import jax
-import jax.numpy as jnp
-
-from repro.configs.registry import smoke_variant
-from repro.core import dglmnet, prox_ref
+"""End-to-end behaviour test for the whole system: the paper's algorithm
+fitting sparse (synthetic) data and generalizing to held-out rows."""
+from repro.core import dglmnet
 from repro.core.dglmnet import DGLMNETConfig
 from repro.data import synthetic
 from repro.data.sparse import to_dense_blocks
-from repro.models import lm
-from repro.models.common import init_params
-from repro.optim import adamw
 
 
 def test_glm_end_to_end_sparse():
@@ -30,39 +19,3 @@ def test_glm_end_to_end_sparse():
     au = synthetic.au_prc(ds.test.y, scores)
     pos_rate = (ds.test.y > 0).mean()
     assert au > pos_rate + 0.15, (au, pos_rate)
-
-
-def test_lm_train_loop_learns(tmp_path):
-    """~1M-param LM, 30 steps: loss must drop on the structured stream."""
-    from repro.runtime.trainer import Trainer, TrainerConfig
-    cfg = smoke_variant("phi4-mini-3.8b")
-    t = Trainer(cfg,
-                adamw.AdamWConfig(lr=3e-3, warmup_steps=5, total_steps=30),
-                TrainerConfig(steps=30, ckpt_every=10,
-                              ckpt_dir=str(tmp_path), async_save=False,
-                              batch=4, seq_len=32))
-    _, _, losses = t.run()
-    assert np.mean(losses[-5:]) < np.mean(losses[:5]) - 0.1, losses
-
-
-def test_serve_loop_greedy_decode():
-    cfg = smoke_variant("gemma3-12b")
-    model = lm.build_model(cfg)
-    params = init_params(model.param_defs(), jax.random.PRNGKey(0))
-    B, S_max = 2, 32
-    prompt = jax.random.randint(jax.random.PRNGKey(1), (B, 8), 0,
-                                cfg.vocab_size)
-    caches = lm.init_cache(cfg, B, S_max)
-    logits, caches = model.forward(params, prompt, mode="prefill",
-                                   caches=caches)
-    tok = jnp.argmax(logits[:, -1], axis=-1)[:, None]
-    outs = [tok]
-    for i in range(8, 14):
-        logits, caches = model.forward(params, tok, mode="decode",
-                                       caches=caches,
-                                       cache_len=jnp.int32(i))
-        tok = jnp.argmax(logits[:, 0], axis=-1)[:, None]
-        outs.append(tok)
-    seq = jnp.concatenate(outs, axis=1)
-    assert seq.shape == (B, 7)
-    assert int(seq.max()) < cfg.vocab_size
